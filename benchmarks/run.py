@@ -43,6 +43,8 @@ SMOKE_SUITES = ("idle", "throughput", "memory", "fleet", "faults")
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     argv = sys.argv[1:]
     smoke = "--smoke" in argv
     if smoke:

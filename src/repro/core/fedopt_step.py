@@ -179,12 +179,17 @@ def init_train_state(rng, cfg: FedStepConfig) -> Params:
 
 
 def _empty_act_slot(cfg: FedStepConfig) -> Params:
-    """One scheduled activation batch (one micro-iteration's output)."""
+    """One scheduled activation batch (one micro-iteration's output).
+    ``valid`` marks the rows some group's emission has been written to:
+    the server loss masks the rest out.  A never-written row holds zeros,
+    and at full depth the gradient through a stack of RMSNorms of an
+    all-zero input overflows to NaN."""
     arch = cfg.arch
     B = cfg.n_groups * cfg.micro_batch
     S = arch.frontend_len if arch.n_decoder_layers else cfg.seq_len
     buf = {"acts": jnp.zeros((B, S, arch.d_model), cfg.param_dtype),
-           "labels": jnp.zeros((B, cfg.seq_len), jnp.int32)}
+           "labels": jnp.zeros((B, cfg.seq_len), jnp.int32),
+           "valid": jnp.zeros((B,), jnp.int32)}
     if arch.n_decoder_layers:
         buf["tokens"] = jnp.zeros((B, cfg.seq_len), jnp.int32)
     if arch.family == "vlm":
@@ -311,8 +316,10 @@ def _act_buf_specs(buf: Params, par: Parallelism, seq_shard: bool,
         if len(shape) == 3:     # (B, S, D) or (B, F, D)
             s = tp if (seq_shard and shape[1] % tp_size == 0) else None
             inner = (b, s, None)
-        else:
+        elif len(shape) == 2:
             inner = (b, None)   # (B, S) int labels/tokens
+        else:
+            inner = (b,)        # (B,) row validity
         return P(None, *inner) if ring else P(*inner)
     return {k: spec(k, v) for k, v in buf.items()}
 
@@ -414,11 +421,13 @@ def make_train_step(cfg: FedStepConfig, par: Parallelism):
             if arch.n_decoder_layers:
                 return tfm.server_encdec_loss(s, arch, buf["acts"],
                                               buf["tokens"], buf["labels"],
-                                              parallelism=srv_par, **kw)
+                                              parallelism=srv_par,
+                                              row_mask=buf["valid"], **kw)
             return tfm.server_forward_loss(s, arch, buf["acts"],
                                            buf["labels"],
                                            frontend=buf.get("frontend"),
-                                           parallelism=srv_par, **kw)
+                                           parallelism=srv_par,
+                                           row_mask=buf["valid"], **kw)
         return jax.value_and_grad(loss_fn)(srv)
 
     def server_half(srv, srv_opt, buf):
@@ -466,7 +475,8 @@ def make_train_step(cfg: FedStepConfig, par: Parallelism):
             dev, aux, acts, d_loss = jax.vmap(device_half)(dev, aux, batch_g)
             G, b = acts.shape[0], acts.shape[1]
             new_buf = {"acts": acts.reshape((G * b,) + acts.shape[2:]),
-                       "labels": batch_g["labels"].reshape(G * b, -1)}
+                       "labels": batch_g["labels"].reshape(G * b, -1),
+                       "valid": jnp.ones((G * b,), jnp.int32)}
             if arch.n_decoder_layers:
                 new_buf["tokens"] = batch_g["tokens"].reshape(G * b, -1)
             if arch.family == "vlm":
@@ -516,7 +526,8 @@ def make_train_step(cfg: FedStepConfig, par: Parallelism):
                 carry = (dev, aux, srv, srv_opt)
             if cfg.pipeline_acts:
                 carry = carry + (ring,)
-            return carry, (jnp.mean(d_loss), s_loss)
+            s_live = jnp.any(train_buf["valid"] > 0).astype(jnp.float32)
+            return carry, (jnp.mean(d_loss), s_loss, s_live)
 
         # (G, H, ...) -> scan-major (H, G, ...); the schedule fields already
         # carry H on the leading axis and pass through unchanged; the
@@ -532,7 +543,7 @@ def make_train_step(cfg: FedStepConfig, par: Parallelism):
                      state["srv_opt"])
         if cfg.pipeline_acts:
             carry = carry + (state["act_buf"],)
-        carry, (d_losses, s_losses) = jax.lax.scan(body, carry, xs)
+        carry, (d_losses, s_losses, s_live) = jax.lax.scan(body, carry, xs)
         if cfg.server_accum:
             dev, aux, srv_acc = carry[:3]
             gs = jax.tree.map(lambda a, p: (a / cfg.H).astype(p.dtype),
@@ -551,7 +562,10 @@ def make_train_step(cfg: FedStepConfig, par: Parallelism):
                          version=state["version"] + 1)
         if cfg.pipeline_acts:
             new_state["act_buf"] = carry[-1]
-        metrics = {"d_loss": jnp.mean(d_losses), "s_loss": jnp.mean(s_losses)}
+        # s_loss: mean over the micro-iterations whose read held any row
+        metrics = {"d_loss": jnp.mean(d_losses),
+                   "s_loss": jnp.sum(s_losses * s_live)
+                   / jnp.maximum(jnp.sum(s_live), 1.0)}
         return new_state, metrics
 
     return step

@@ -21,7 +21,11 @@ H//Hkv GQA query-head group in VMEM scratch).  Soft-capping contributes the
 tanh-derivative factor (1 - (z/cap)²) to dS.
 
 Layout: q (B, H, S, hd); k, v (B, Hkv, Skv, hd).  `ops.flash_attention`
-wraps the (B, S, H, hd) public layout.
+wraps the (B, S, H, hd) public layout.  Per-row statistics (m, l, lse, Δ)
+are held as (rows, LANES) tiles, replicated across the 128 lanes as in
+JAX's own TPU flash attention: Mosaic needs a block's last two dims
+(8, 128)-aligned or whole, which a (1, block_q) slice of a (B, H, S)
+array is not.  The kernels read them back as (rows, 1) columns.
 """
 from __future__ import annotations
 
@@ -34,6 +38,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANES = 128        # lane width of a TPU vreg: row statistics fill one tile
+
+
+def _lanes(col):
+    """(rows, 1) column -> (rows, LANES) lane-replicated tile."""
+    return jnp.broadcast_to(col, (col.shape[0], LANES))
 
 
 def _tile_mask(q_start, k_start, *, causal, window, block_q, block_k, seq_k):
@@ -90,18 +100,18 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
                           block_q=block_q, block_k=block_k, seq_k=seq_k)
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_scr[...]
-        l_prev = l_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_prev = m_scr[...][:, :1]                      # (bq, 1) columns
+        l_prev = l_scr[...][:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)                 # NEG_INF-safe: exp(-inf)≈0
-        p = jnp.exp(s - m_new[:, None])
+        p = jnp.exp(s - m_new)
         p = jnp.where(mask, p, 0.0)
-        l_new = alpha * l_prev + jnp.sum(p, axis=1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + \
+        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + \
             jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
-        l_scr[...] = l_new
+        m_scr[...] = _lanes(m_new)
+        l_scr[...] = _lanes(l_new)
 
     # tile-level skip: in causal/window mode many (i, j) tiles are fully
     # masked — skip their compute entirely (TPU analogue of early exit).
@@ -113,13 +123,14 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(j == nk - 1)
     def _finalize():
-        l = l_scr[...]
+        l = l_scr[...][:, :1]
         safe = jnp.where(l > 0.0, l, 1.0)
-        o_ref[0, 0] = (acc_scr[...] / safe[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / safe).astype(o_ref.dtype)
         # LSE of the masked row; fully-masked rows keep NEG_INF so the
         # backward's exp(z - lse) stays mask-zeroed rather than NaN.
-        lse_ref[0, 0] = jnp.where(l > 0.0, m_scr[...] + jnp.log(safe),
-                                  NEG_INF)
+        lse_ref[0, 0] = _lanes(jnp.where(l > 0.0,
+                                         m_scr[...][:, :1] + jnp.log(safe),
+                                         NEG_INF))
 
 
 def _pad_to(x, axis, mult):
@@ -171,20 +182,21 @@ def flash_attention_fwd_bhsd(q, k, v, *, causal=True, window=None,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, i, j: (b, h, i)),
+            pl.BlockSpec((1, 1, block_q, LANES),
+                         lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Sp, hd), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Sp), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sp, LANES), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, LANES), jnp.float32),
+            pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
         interpret=interpret,
     )(qp, kp, vp)
-    return out[:, :, :S], lse[:, :, :S]
+    return out[:, :, :S], lse[:, :, :S, 0]
 
 
 def flash_attention_bhsd(q, k, v, *, causal=True, window=None, logit_cap=None,
@@ -200,12 +212,13 @@ def flash_attention_bhsd(q, k, v, *, causal=True, window=None, logit_cap=None,
 # Backward kernels (recompute from q, k, v, lse — FlashAttention-2 schedule)
 # ---------------------------------------------------------------------------
 
-def _tile_p_ds(q, k, v, do, lse_row, delta_row, mask, *, scale, logit_cap):
+def _tile_p_ds(q, k, v, do, lse, delta, mask, *, scale, logit_cap):
     """Rebuild one attention tile's probabilities p and logit-gradient dS.
 
     z = softcap(scale·qkᵀ); p = exp(z - lse); dS = p·(doᵀv - Δ) with the
-    tanh-derivative factor (1 - (z/cap)²) when soft-capped.  Fully-masked
-    rows carry lse = NEG_INF; the mask zeroes p there before any use.
+    tanh-derivative factor (1 - (z/cap)²) when soft-capped.  ``lse`` and
+    ``delta`` are (bq, 1) columns.  Fully-masked rows carry lse = NEG_INF;
+    the mask zeroes p there before any use.
     """
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -213,11 +226,11 @@ def _tile_p_ds(q, k, v, do, lse_row, delta_row, mask, *, scale, logit_cap):
         z = logit_cap * jnp.tanh(s / logit_cap)
     else:
         z = s
-    p = jnp.exp(z - lse_row[:, None])
+    p = jnp.exp(z - lse)
     p = jnp.where(mask, p, 0.0)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_row[:, None])
+    ds = p * (dp - delta)
     if logit_cap is not None:
         ds = ds * (1.0 - jnp.square(z / logit_cap))    # d softcap / d s
     return p, ds
@@ -246,7 +259,8 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         do = do_ref[0, 0].astype(jnp.float32)
         mask = _tile_mask(q_start, k_start, causal=causal, window=window,
                           block_q=block_q, block_k=block_k, seq_k=seq_k)
-        _, ds = _tile_p_ds(q, k, v, do, lse_ref[0, 0], delta_ref[0, 0], mask,
+        _, ds = _tile_p_ds(q, k, v, do, lse_ref[0, 0][:, :1],
+                           delta_ref[0, 0][:, :1], mask,
                            scale=scale, logit_cap=logit_cap)
         dq_scr[...] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
@@ -292,7 +306,8 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         do = do_ref[0, 0].astype(jnp.float32)
         mask = _tile_mask(q_start, k_start, causal=causal, window=window,
                           block_q=block_q, block_k=block_k, seq_k=seq_k)
-        p, ds = _tile_p_ds(q, k, v, do, lse_ref[0, 0], delta_ref[0, 0], mask,
+        p, ds = _tile_p_ds(q, k, v, do, lse_ref[0, 0][:, :1],
+                           delta_ref[0, 0][:, :1], mask,
                            scale=scale, logit_cap=logit_cap)
         dv_scr[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
@@ -331,8 +346,9 @@ def flash_attention_bwd_bhsd(q, k, v, o, lse, do, *, causal=True, window=None,
 
     qp, op, dop = (_pad_to(t, 2, block_q) for t in (q, o, do))
     kp, vp = (_pad_to(t, 2, block_k) for t in (k, v))
-    lsep = _pad_to(lse, 2, block_q)
-    deltap = _pad_to(delta, 2, block_q)
+    lsep, deltap = (jnp.broadcast_to(_pad_to(t, 2, block_q)[..., None],
+                                     (B, H, qp.shape[2], LANES))
+                    for t in (lse, delta))
     Sp, Skvp = qp.shape[2], kp.shape[2]
     if Sp % block_q or Skvp % block_k:
         raise ValueError(
@@ -355,8 +371,10 @@ def flash_attention_bwd_bhsd(q, k, v, o, lse, do, *, causal=True, window=None,
             pl.BlockSpec((1, 1, block_k, hd),
                          lambda b, h, i, j, g=group: (b, h // g, j, 0)),
             pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, i, j: (b, h, i)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, i, j: (b, h, i)),
+            pl.BlockSpec((1, 1, block_q, LANES),
+                         lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_q, LANES),
+                         lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, hd),
                                lambda b, h, i, j: (b, h, i, 0)),
@@ -377,10 +395,10 @@ def flash_attention_bwd_bhsd(q, k, v, o, lse, do, *, causal=True, window=None,
                          lambda b, kh, j, g, i: (b, kh, j, 0)),
             pl.BlockSpec((1, 1, block_q, hd),
                          lambda b, kh, j, g, i, gr=group: (b, kh * gr + g, i, 0)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda b, kh, j, g, i, gr=group: (b, kh * gr + g, i)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda b, kh, j, g, i, gr=group: (b, kh * gr + g, i)),
+            pl.BlockSpec((1, 1, block_q, LANES),
+                         lambda b, kh, j, g, i, gr=group: (b, kh * gr + g, i, 0)),
+            pl.BlockSpec((1, 1, block_q, LANES),
+                         lambda b, kh, j, g, i, gr=group: (b, kh * gr + g, i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, hd),

@@ -1,12 +1,13 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels run with interpret=True — the kernel
-body executes in Python per grid step, validating the exact TPU program
-logic.  On TPU backends they compile to Mosaic.  Interpret mode is decided
-per-call (``interpret=``), scoped (``kernel_mode``), or globally
-(``set_kernel_mode``); it is resolved OUTSIDE the jit boundary and passed
-as a static argument, so overrides actually retrace instead of being
-swallowed by the jit cache.
+On a TPU the kernels compile to Mosaic (``tests/test_tpu_compile.py``
+compiles them for a described v5e; ``chip_smoke.py`` runs them on one).
+On the CPU backend they run with interpret=True: the kernel body executes
+per grid step, which checks the program's logic but not Mosaic's tiling
+rules.  Interpret mode is decided per-call (``interpret=``), scoped
+(``kernel_mode``), or globally (``set_kernel_mode``); it is resolved
+OUTSIDE the jit boundary and passed as a static argument, so overrides
+actually retrace instead of being swallowed by the jit cache.
 
 Both ops are differentiable: ``jax.custom_vjp`` routes their backward
 passes through the fused Pallas backward kernels (FlashAttention-style
@@ -146,15 +147,18 @@ _ssd.defvjp(_ssd_fwd, _ssd_bwd)
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
 def _ssd_jit(x, dt, A, Bm, Cm, *, chunk, interpret):
+    """(B, T, ·) model layout -> the kernels' head-major (B, ·, T) layout,
+    T padded to a chunk multiple, and back."""
     T = x.shape[1]
     pad = (-T) % chunk
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
-        Bm = jnp.pad(Bm, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        Cm = jnp.pad(Cm, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    y = _ssd(x, dt, A, Bm, Cm, chunk, interpret)
-    return y[:, :T]
+
+    def head_major(t):
+        t = jnp.swapaxes(t, 1, 2)
+        return jnp.pad(t, [(0, 0), (0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 3))
+
+    y = _ssd(head_major(x), head_major(dt), A, head_major(Bm),
+             head_major(Cm), chunk, interpret)
+    return jnp.swapaxes(y[:, :, :T], 1, 2)
 
 
 def ssd(x, dt, A, Bm, Cm, *, chunk=128, interpret=None):

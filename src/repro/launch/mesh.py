@@ -17,19 +17,30 @@ mesh (DP over pod×data, TP over model).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple, axes: tuple):
+    """``jax.make_mesh`` with every axis ``Auto``.  The train step is
+    written as GSPMD annotations (``parallel/sharding.py``); JAX's default
+    ``Explicit`` axes would type-check shardings through the vmap over FL
+    groups and refuse it."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 2, *, pod: int = 0):
-    """Small mesh for CPU smoke tests (requires host-device override)."""
+    """Small mesh over the first devices: ``(data, model)``, or
+    ``(pod, data, model)`` with ``pod``.  On the CPU more than one device
+    needs ``--xla_force_host_platform_device_count``."""
     if pod:
-        return jax.make_mesh((pod, n_data, n_model), ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+        return _auto_mesh((pod, n_data, n_model), ("pod", "data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))
 
 
 def dp_axes_of(mesh) -> tuple:

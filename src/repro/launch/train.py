@@ -60,6 +60,7 @@ from repro.faults import (POD_CLASSES, SIM_CLASSES, FaultSchedule,
                           make_fault_schedule)
 from repro.fleet import (FleetTrace, SelectionContext, balance_summary,
                          make_selection_policy, make_trace, sample_cluster)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_debug_mesh, n_groups_of
 from repro.memory import ActivationStore
 from repro.obs.metrics import MetricsRegistry
@@ -477,6 +478,8 @@ def run_pod(args) -> dict:
               f"roster events={absences}  "
               f"selection={sel.describe() if sel else 'all'}")
     out = {"history": history, "final": history[-1] if history else None,
+           "state": state,
+           "round_wall_s": [s.round_wall_s for s in executor.stats],
            "executor": xs, "memory": mem,
            "consumed": consumed.tolist(), "contribution_balance": bal,
            "registry": executor.metrics.snapshot()}
@@ -614,7 +617,9 @@ def run_sim(args) -> dict:
     return out
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
+    """The CLI; ``build_parser().parse_args([...])`` gives programmatic
+    callers the same defaults as the command line."""
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--mode", default="pod", choices=("pod", "sim"))
     p.add_argument("--arch", default="smollm-135m")
@@ -728,7 +733,12 @@ def main() -> None:
     p.add_argument("--devices", type=int, default=8)
     p.add_argument("--duration", type=float, default=300.0)
     p.add_argument("--seed", type=int, default=0)
-    args = p.parse_args()
+    return p
+
+
+def main() -> None:
+    args = build_parser().parse_args()
+    enable_compile_cache()
     run = run_pod if args.mode == "pod" else run_sim
 
     def _run_traced():

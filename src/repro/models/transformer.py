@@ -647,13 +647,21 @@ def device_train_loss(dev_params: Params, aux_params: Params, cfg: ArchConfig,
     return loss, acts
 
 
+def _row_mask(row_mask, labels):
+    """(B,) row mask -> the (B, S) token mask of ``chunked_ce_loss``."""
+    if row_mask is None:
+        return None
+    return jnp.broadcast_to(row_mask[:, None], labels.shape)
+
+
 def server_forward_loss(srv_params: Params, cfg: ArchConfig, acts, labels, *,
                         frontend=None, use_kernel=False, parallelism=None,
-                        remat=True, aux_weight=0.01):
+                        remat=True, aux_weight=0.01, row_mask=None):
     """Server-side objective F_s (Eq. 5): centralized training on activations
     ξ ~ A.  `acts` arrive detached (lax.stop_gradient at call site mirrors
     the no-gradient-to-device property).  `frontend` carries patch/frame
-    embeddings for server-side cross-attention layers (VLM)."""
+    embeddings for server-side cross-attention layers (VLM).  `row_mask`
+    (B,) drops rows that hold no emission from the loss and its gradient."""
     acts = jax.lax.stop_gradient(acts)
     positions = jnp.arange(acts.shape[1])[None, :]
     h, moe_aux = _run_stack(srv_params["blocks"], cfg, acts,
@@ -665,13 +673,13 @@ def server_forward_loss(srv_params: Params, cfg: ArchConfig, acts, labels, *,
         head = {"lm_head": srv_params["lm_head"]}
     else:
         head = {"embed": srv_params["embed_out"]}
-    loss = chunked_ce_loss(head, cfg, h, labels)
+    loss = chunked_ce_loss(head, cfg, h, labels, _row_mask(row_mask, labels))
     return loss + aux_weight * moe_aux
 
 
 def server_encdec_loss(srv_params: Params, cfg: ArchConfig, acts, tokens,
                        labels, *, use_kernel=False, parallelism=None,
-                       remat=True, aux_weight=0.01):
+                       remat=True, aux_weight=0.01, row_mask=None):
     """Server-side objective for enc-dec archs (whisper): finish the encoder
     on the device activations, then run the full decoder with cross-attn to
     the final encoder states, next-token CE on the local transcript."""
@@ -692,5 +700,5 @@ def server_encdec_loss(srv_params: Params, cfg: ArchConfig, acts, tokens,
     h = rmsnorm_apply(srv_params["dec_norm"], h)
     head = {"embed": srv_params["embed_out"]} if "embed_out" in srv_params \
         else {"lm_head": srv_params["lm_head"]}
-    loss = chunked_ce_loss(head, cfg, h, labels)
+    loss = chunked_ce_loss(head, cfg, h, labels, _row_mask(row_mask, labels))
     return loss + aux_weight * (aux_e + aux_d)

@@ -19,19 +19,11 @@ from dataclasses import dataclass
 import jax
 from jax.sharding import PartitionSpec as P
 
-if hasattr(jax, "shard_map"):                       # jax >= 0.6
-    def shard_map(f, mesh, in_specs, out_specs):
-        """Version-compat shard_map (replication checking off: the MoE
-        psum pattern trips the checker on some jax versions)."""
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:                                               # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_experimental
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        """Version-compat shard_map (see above)."""
-        return _shard_map_experimental(f, mesh=mesh, in_specs=in_specs,
-                                       out_specs=out_specs, check_rep=False)
+def shard_map(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with replication checking off: the MoE psum
+    pattern trips the checker."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 @dataclass(frozen=True)

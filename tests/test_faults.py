@@ -229,6 +229,30 @@ def test_resume_all_torn_initializes_fresh(tmp_path):
     np.testing.assert_array_equal(state["w"], _tree(0)["w"])
 
 
+def test_crash_sweep_parent_never_starts_a_backend(tmp_path):
+    """The sweep's parent reads snapshots through numpy and the filesystem
+    only.  A parent that started a JAX backend would hold the chip, and
+    its ``launch.train`` children would then fail or hang on it."""
+    import subprocess
+    import sys
+    store.save(str(tmp_path), 2, _tree(2), metadata={"rng_state": [7]})
+    code = (
+        "import sys\n"
+        "from jax._src import xla_bridge\n"
+        "from repro.faults import crash_harness\n"
+        "fp = crash_harness._final_fingerprint(sys.argv[1], 2)\n"
+        "assert fp['rng_state'] == [7], fp\n"
+        "assert not xla_bridge.backends_are_initialized()\n")
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "src"), env.get("PYTHONPATH", "")])
+    proc = subprocess.run([sys.executable, "-c", code, str(tmp_path)],
+                          env=env, capture_output=True, text=True,
+                          timeout=300)
+    assert proc.returncode == 0, proc.stderr
+
+
 def test_churn_draw_is_time_indexed_not_call_ordered():
     """Satellite pin: ChurnModel.draw(t) is a pure function of
     (seed, interval index) — call order and call count must not matter."""

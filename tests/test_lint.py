@@ -70,10 +70,16 @@ def test_rp002_wallclock(tmp_path):
             return time.time()
     """)
     assert _rules_of(lint_file(bad)) == ["RP002"]
-    good = _write(tmp_path, "fleet/traces2.py", """
+    # perf_counter is a wall clock too: intervals read the obs clock
+    assert _rules_of(lint_file(_write(tmp_path, "fleet/traces3.py", """
         import time
         def tick():
             return time.perf_counter()
+    """))) == ["RP002"]
+    good = _write(tmp_path, "fleet/traces2.py", """
+        from repro.obs.clock import now
+        def tick():
+            return now()
     """)
     assert lint_file(good) == []
 
