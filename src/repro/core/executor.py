@@ -322,6 +322,7 @@ class RoundExecutor:
         self._churn_seen = False
         self.handles = HandleRing(depth=window + 1)
         self._deferred: deque[RoundHandle] = deque()   # no-flush saves
+        self._op_table_sent = False
 
     # legacy counter names, read-only over the registry instruments
     @property
@@ -408,12 +409,17 @@ class RoundExecutor:
                 _tr.emit_span("host/plan", "plan_round", t0, t1, round=int(r))
                 _tr.emit_span("host/build", "build_batch", t1, t2,
                               round=int(r))
+                if not self._op_table_sent and hasattr(self.step, "lower"):
+                    self._emit_op_table(r, state, batch)
             st = RoundStats(round=r, plan_s=t1 - t0, build_s=t2 - t1,
                             in_flight_at_dispatch=len(self._pending),
                             plan=plan, _host_t0=t0, _dispatch_t=t2)
             state, metrics = self.step(state, batch)
             self.cplane.finish_round(active=active)
             self._check_cap(r)
+            if _tr.TRACING:
+                _tr.emit_span("host/dispatch", "dispatch", t2, _now(),
+                              round=int(r))
             if _san.TRACING:
                 _san.emit("exec.round", cp=self.cplane, store=self.store,
                           round=int(r), in_flight=len(self._pending))
@@ -448,6 +454,32 @@ class RoundExecutor:
         return state, history
 
     # ------------------------------------------------------------------
+    def _emit_op_table(self, r: int, state, batch):
+        """One ``host/compile`` span naming the round program's parts:
+        ``op_scope`` maps each instruction of the compiled step (the
+        executable the dispatch runs: ``lower().compile()`` fills the
+        jit's own cache) to its named scope (``repro.obs.scopes``) or
+        None, the key a device trace's op names are joined on.
+
+        The persistent compilation cache keys programs without their
+        metadata, so an executable it holds may carry the op names of
+        an older build of the same program; this compile keys them in,
+        and the table names the scopes of the code that runs."""
+        import jax
+
+        from repro.obs.scopes import op_scopes
+        self._op_table_sent = True
+        tc0 = _now()
+        key = "jax_compilation_cache_include_metadata_in_key"
+        prev = getattr(jax.config, key)
+        jax.config.update(key, True)
+        try:
+            text = self.step.lower(state, batch).compile().as_text()
+        finally:
+            jax.config.update(key, prev)
+        _tr.emit_span("host/compile", "op_table", tc0, _now(), round=int(r),
+                      op_scope=op_scopes(text))
+
     def _light_keys(self) -> tuple:
         """Leaves the NEXT boundary's consumers may slice from this
         round's handle.  Adaptive: no spill pool and no churn so far
@@ -615,6 +647,10 @@ class RoundExecutor:
         t_fetch = _now()
         m = {k: float(v) for k, v in metrics.items()}   # blocks here only
         t = _now()
+        if _tr.TRACING:
+            # emitted before the hooks, which may raise out of the drain
+            _tr.emit_span("host/drain", "drain", t_fetch, t,
+                          round=int(st.round))
         # device-completion estimate: a blocking fetch pins the completion
         # at its return; a non-blocking fetch means the round finished at
         # some unobservable earlier point — fall back to its dispatch time
@@ -645,27 +681,14 @@ class RoundExecutor:
         self._h_plan.observe(st.plan_s)
         self._h_build.observe(st.build_s)
         self._h_wall.observe(wall)
-        if _tr.TRACING:
-            # mesh busy: dispatch → observed completion (clipped so
-            # pipelined rounds tile the lane instead of overlapping);
-            # device lanes mirror it for the groups the plan broadcast to
-            _tr.emit_span("host/drain", "drain", t_fetch, t,
-                          round=int(st.round))
-            end = completion if completion > st._dispatch_t \
-                else st._dispatch_t + wall
-            _tr.emit_span("mesh", "round", st._dispatch_t, end,
-                          clip=True, round=int(st.round))
-            if st.plan is not None and \
-                    getattr(st.plan, "bcast_mask", None) is not None:
-                for g in np.nonzero(
-                        np.asarray(st.plan.bcast_mask) > 0.5)[0]:
-                    _tr.emit_span(f"dev/{int(g)}", "round",
-                                  st._dispatch_t, end, clip=True,
-                                  round=int(st.round))
         self.stats.append(st)
         history.append(m)
         if on_metrics is not None:
             on_metrics(st.round, m, st)
+        if _tr.TRACING:
+            # the round's accounting, profile update and drain hook
+            _tr.emit_span("host/record", "record_round", t, _now(),
+                          round=int(st.round))
         # the full RoundPlan (H×G schedule arrays) is only needed through
         # the drain hook; keep the per-round stats list O(scalars) so long
         # runs don't accumulate plans

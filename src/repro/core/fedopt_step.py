@@ -60,10 +60,14 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.models import transformer as tfm
 from repro.models.api import ArchConfig
+from repro.obs.scopes import SCOPES
 from repro.optim.optimizers import make_optimizer
 from repro.parallel.sharding import Parallelism, param_specs, _param_spec, _validate
 
 Params = Any
+
+#: ``jax.named_scope`` names of the round's parts (``repro.obs.scopes``)
+DEVICE_HALF, SERVER_HALF, RING, AGGREGATE = SCOPES
 
 
 # ---------------------------------------------------------------------------
@@ -391,6 +395,7 @@ def make_train_step(cfg: FedStepConfig, par: Parallelism):
                       moe_interior=cfg.ep_interior)
     kw = dict(use_kernel=cfg.use_kernel, remat=cfg.remat)
 
+    @jax.named_scope(DEVICE_HALF)
     def device_half(dev, aux, batch_g):
         """One FL device group: local-loss training (Alg. 1 lines 3-12).
         Runs under vmap over the group axis — no cross-group collectives."""
@@ -436,6 +441,7 @@ def make_train_step(cfg: FedStepConfig, par: Parallelism):
         srv, srv_opt = s_update(srv, gs, srv_opt, cfg.lr_s)
         return srv, srv_opt, s_loss
 
+    @jax.named_scope(AGGREGATE)
     def aggregate(dev_aux, weights, recv_mask):
         """Async staleness-weighted aggregation over the group axis (Alg. 4
         lines 12-19 telescoped: the sequential α-lerps over one round equal
@@ -460,6 +466,53 @@ def make_train_step(cfg: FedStepConfig, par: Parallelism):
 
         return jax.tree.map(mean_bcast, dev_aux)
 
+    @jax.named_scope(RING)
+    def exchange(acts, batch_g, batch_h, ring):
+        """This micro-iteration's activation batch into the ω ring, and
+        the batch the server trains on out of it."""
+        G, b = acts.shape[0], acts.shape[1]
+        new_buf = {"acts": acts.reshape((G * b,) + acts.shape[2:]),
+                   "labels": batch_g["labels"].reshape(G * b, -1),
+                   "valid": jnp.ones((G * b,), jnp.int32)}
+        if arch.n_decoder_layers:
+            new_buf["tokens"] = batch_g["tokens"].reshape(G * b, -1)
+        if arch.family == "vlm":
+            new_buf["frontend"] = batch_g["frontend"].reshape(
+                (G * b,) + batch_g["frontend"].shape[2:])
+        if cfg.seq_shard_acts:
+            spec = _act_buf_specs({"acts": new_buf["acts"]}, par,
+                                  True)["acts"]
+            new_buf["acts"] = jax.lax.with_sharding_constraint(
+                new_buf["acts"], NamedSharding(par.mesh, spec))
+
+        if cfg.pipeline_acts:
+            # server consumes the host-scheduled slot (ring state from
+            # BEFORE this iteration's write, matching the control
+            # plane's read-then-write bookkeeping) ...
+            read_slot = batch_h["read_slot"]
+            train_buf = jax.tree.map(
+                lambda x: jax.lax.dynamic_index_in_dim(
+                    x, read_slot, 0, keepdims=False), ring)
+            # ... while token-holding groups' rows refresh the written
+            # slot; groups without a flow-control grant keep the slot's
+            # previous content (their emission is not shipped)
+            write_slot = batch_h["write_slot"]
+            keep = batch_h["send_mask"] > 0.5            # (G,)
+            rows = jnp.repeat(keep, b)                   # (G*b,) grouped
+            old = jax.tree.map(
+                lambda x: jax.lax.dynamic_index_in_dim(
+                    x, write_slot, 0, keepdims=False), ring)
+            merged = jax.tree.map(
+                lambda n, o: jnp.where(
+                    rows.reshape((-1,) + (1,) * (n.ndim - 1)), n, o),
+                new_buf, old)
+            ring = jax.tree.map(
+                lambda r, m: jax.lax.dynamic_update_index_in_dim(
+                    r, m, write_slot, 0), ring, merged)
+        else:
+            train_buf = new_buf
+        return train_buf, ring
+
     def step(state, batch):
         srv_const = state["srv"] if cfg.server_accum else None
 
@@ -473,57 +526,20 @@ def make_train_step(cfg: FedStepConfig, par: Parallelism):
                        if k not in SCHEDULE_KEYS}
 
             dev, aux, acts, d_loss = jax.vmap(device_half)(dev, aux, batch_g)
-            G, b = acts.shape[0], acts.shape[1]
-            new_buf = {"acts": acts.reshape((G * b,) + acts.shape[2:]),
-                       "labels": batch_g["labels"].reshape(G * b, -1),
-                       "valid": jnp.ones((G * b,), jnp.int32)}
-            if arch.n_decoder_layers:
-                new_buf["tokens"] = batch_g["tokens"].reshape(G * b, -1)
-            if arch.family == "vlm":
-                new_buf["frontend"] = batch_g["frontend"].reshape(
-                    (G * b,) + batch_g["frontend"].shape[2:])
-            if cfg.seq_shard_acts:
-                spec = _act_buf_specs({"acts": new_buf["acts"]}, par,
-                                      True)["acts"]
-                new_buf["acts"] = jax.lax.with_sharding_constraint(
-                    new_buf["acts"], NamedSharding(par.mesh, spec))
+            train_buf, ring = exchange(acts, batch_g, batch_h, ring)
 
-            if cfg.pipeline_acts:
-                # server consumes the host-scheduled slot (ring state from
-                # BEFORE this iteration's write, matching the control
-                # plane's read-then-write bookkeeping) ...
-                read_slot = batch_h["read_slot"]
-                train_buf = jax.tree.map(
-                    lambda x: jax.lax.dynamic_index_in_dim(
-                        x, read_slot, 0, keepdims=False), ring)
-                # ... while token-holding groups' rows refresh the written
-                # slot; groups without a flow-control grant keep the slot's
-                # previous content (their emission is not shipped)
-                write_slot = batch_h["write_slot"]
-                keep = batch_h["send_mask"] > 0.5            # (G,)
-                rows = jnp.repeat(keep, b)                   # (G*b,) grouped
-                old = jax.tree.map(
-                    lambda x: jax.lax.dynamic_index_in_dim(
-                        x, write_slot, 0, keepdims=False), ring)
-                merged = jax.tree.map(
-                    lambda n, o: jnp.where(
-                        rows.reshape((-1,) + (1,) * (n.ndim - 1)), n, o),
-                    new_buf, old)
-                ring = jax.tree.map(
-                    lambda r, m: jax.lax.dynamic_update_index_in_dim(
-                        r, m, write_slot, 0), ring, merged)
-            else:
-                train_buf = new_buf
-
-            if cfg.server_accum:
-                # θ_s loop-invariant: grads accumulate, FSDP gathers hoist
-                s_loss, gs = server_grads(srv_const, train_buf)
-                srv_acc = jax.tree.map(
-                    lambda a, g: a + g.astype(jnp.float32), srv_acc, gs)
-                carry = (dev, aux, srv_acc)
-            else:
-                srv, srv_opt, s_loss = server_half(srv, srv_opt, train_buf)
-                carry = (dev, aux, srv, srv_opt)
+            with jax.named_scope(SERVER_HALF):
+                if cfg.server_accum:
+                    # θ_s loop-invariant: grads accumulate, FSDP gathers
+                    # hoist
+                    s_loss, gs = server_grads(srv_const, train_buf)
+                    srv_acc = jax.tree.map(
+                        lambda a, g: a + g.astype(jnp.float32), srv_acc, gs)
+                    carry = (dev, aux, srv_acc)
+                else:
+                    srv, srv_opt, s_loss = server_half(srv, srv_opt,
+                                                       train_buf)
+                    carry = (dev, aux, srv, srv_opt)
             if cfg.pipeline_acts:
                 carry = carry + (ring,)
             s_live = jnp.any(train_buf["valid"] > 0).astype(jnp.float32)
@@ -546,10 +562,11 @@ def make_train_step(cfg: FedStepConfig, par: Parallelism):
         carry, (d_losses, s_losses, s_live) = jax.lax.scan(body, carry, xs)
         if cfg.server_accum:
             dev, aux, srv_acc = carry[:3]
-            gs = jax.tree.map(lambda a, p: (a / cfg.H).astype(p.dtype),
-                              srv_acc, state["srv"])
-            srv, srv_opt = s_update(state["srv"], gs, state["srv_opt"],
-                                    cfg.lr_s)
+            with jax.named_scope(SERVER_HALF):
+                gs = jax.tree.map(lambda a, p: (a / cfg.H).astype(p.dtype),
+                                  srv_acc, state["srv"])
+                srv, srv_opt = s_update(state["srv"], gs, state["srv_opt"],
+                                        cfg.lr_s)
         else:
             dev, aux, srv, srv_opt = carry[:4]
 

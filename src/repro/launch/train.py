@@ -297,8 +297,10 @@ def run_pod(args) -> dict:
         resumed_meta = meta
         print(f"resumed from round {start_round}")
     else:
-        state = jax.jit(lambda: F.init_train_state(
-            jax.random.PRNGKey(args.seed), cfg), out_shardings=s_spec)()
+        # the key is an argument, not a constant, so the compiled init
+        # (minutes at full width) is one cache entry for every seed
+        state = jax.jit(lambda key: F.init_train_state(key, cfg),
+                        out_shardings=s_spec)(jax.random.PRNGKey(args.seed))
 
     streams = _group_streams(cfg, seed=args.seed)
     rng = np.random.default_rng(args.seed + start_round)

@@ -13,6 +13,8 @@ Three pieces, no third-party deps:
   accounting; snapshots ride ``BENCH_*.json`` records.
 * :mod:`repro.obs.clock` — the blessed wall-clock (``now()``) for
   instrumented hot paths (lint rule RP002 requires it there).
+* :mod:`repro.obs.scopes` — the named scopes of the pod round program
+  and the table placing a compiled round's instructions in them.
 """
 from .clock import now  # noqa: F401
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry  # noqa: F401

@@ -24,7 +24,7 @@ assigned to exactly one class:
 
 Entities aggregate lanes: device *k* is every ``dev/<k>`` and
 ``dev/<k>/...`` lane (PiPar's overlapped-forward sub-lane counts as the
-same device being busy); the server is ``srv``, ``srv/...`` and ``mesh``.
+same device being busy); the server is ``srv`` and ``srv/...``.
 ``net/`` and ``host/`` lanes are timeline detail, not compute, and are
 ignored here.
 
@@ -74,7 +74,7 @@ def _device_of(lane: str):
 
 
 def _is_server(lane: str) -> bool:
-    return lane == "srv" or lane.startswith("srv/") or lane == "mesh"
+    return lane == "srv" or lane.startswith("srv/")
 
 
 def attribute_idle(tracer, duration: float | None = None) -> dict:

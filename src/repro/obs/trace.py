@@ -18,10 +18,13 @@ Lanes and time domains
 ----------------------
 
 A *lane* is a string naming one timeline: ``dev/<k>`` (device compute),
-``net/<k>`` (device uplink), ``srv`` (server compute), ``mesh`` (the pod
-mesh), ``host/<phase>`` (pod host loop: plan, build, drain, memory,
-capture, ckpt, control).  Chrome export maps lanes onto pid/tid rows:
-pid 1 = server/host lanes, pid 2 = devices, pid 3 = network.
+``net/<k>`` (device uplink), ``srv`` (server compute), ``host/<phase>``
+(pod host loop: plan, build, dispatch, drain, record, memory, capture,
+ckpt, control, and ``compile``, whose one ``op_table`` span carries the
+compiled round's instruction→scope table, ``repro.obs.scopes``).  The
+pod's device time is not a span: it is read from a device trace.  Chrome
+export maps lanes onto pid/tid rows: pid 1 = server/host lanes, pid 2 =
+devices, pid 3 = network.
 
 Every span carries explicit ``t0``/``t1`` in the tracer's ``domain``:
 ``"wall"`` (``repro.obs.clock.now()`` seconds — pod runs) or ``"sim"``

@@ -280,6 +280,7 @@ class TestExecutorTrace:
 
         from repro.core.control_plane import ControlPlane
         from repro.core.executor import RoundExecutor
+        from repro.obs.clock import now
 
         G = 4
         cp = ControlPlane(G, 2, 4)
@@ -289,22 +290,79 @@ class TestExecutorTrace:
             with ExitStack() as stack:
                 if tracer is not None:
                     stack.enter_context(traced(tracer))
+                t0 = now()
                 ex.run(0, 0, 6,
                        active_fn=lambda r: np.ones(G, bool),
                        batch_fn=lambda r, plan: {})
+                self.run_span = (t0, now())
         finally:
             dev.close()
         return ex
 
-    def test_window4_trace_has_mesh_and_device_lanes(self):
+    def test_window4_trace_has_host_lanes_only(self):
+        """The pod trace's lanes are the host loop's: the mesh's time is
+        read from a device trace, so no ``mesh`` or ``dev/`` lane is
+        emitted, and the host lanes validate."""
         tr = Tracer(domain="wall")
         ex = self._run(4, tracer=tr)
         lanes = tr.lanes()
-        assert "mesh" in lanes
-        assert any(ln.startswith("dev/") for ln in lanes)
-        assert any(ln.startswith("host/") for ln in lanes)
+        assert {"host/plan", "host/build", "host/dispatch", "host/drain",
+                "host/record"} <= set(lanes)
+        assert "mesh" not in lanes
+        assert not any(ln.startswith("dev/") for ln in lanes)
         assert validate_chrome_trace(tr.to_chrome()) == []
         assert ex.peak_in_flight == 4
+
+    def test_one_dispatch_span_per_round(self):
+        tr = Tracer(domain="wall")
+        self._run(4, tracer=tr)
+        rounds = [a["round"] for ln, _, _, _, a in tr.spans
+                  if ln == "host/dispatch"]
+        assert rounds == list(range(6))
+
+    def test_host_spans_cover_the_round_loop(self):
+        """From round 1's plan to the end of ``run``, the host lanes
+        leave under 5% of the wall time without a span."""
+        tr = Tracer(domain="wall")
+        self._run(4, tracer=tr)
+        lo = min(t0 for ln, _, t0, _, a in tr.spans
+                 if ln == "host/plan" and a["round"] == 1)
+        hi = self.run_span[1]
+        covered, end = 0.0, lo
+        for t0, t1 in sorted((max(t0, lo), min(t1, hi))
+                             for ln, _, t0, t1, _ in tr.spans
+                             if ln.startswith("host/")):
+            if t1 > end:
+                covered += t1 - max(t0, end)
+                end = t1
+        assert covered >= 0.95 * (hi - lo), (covered, hi - lo)
+
+    def test_drain_span_survives_a_raising_hook(self):
+        """A drain hook that ends the run by raising (as a benchmark's
+        window does) still leaves that round's fetch under a span."""
+        from repro.core.control_plane import ControlPlane
+        from repro.core.executor import RoundExecutor
+
+        class Stop(Exception):
+            pass
+
+        def stop_at_2(r, m, st):
+            if r == 2:
+                raise Stop
+
+        dev = _AsyncStub(0.01)
+        try:
+            ex = RoundExecutor(dev.step, ControlPlane(4, 2, 4), window=2)
+            with traced(Tracer(domain="wall")) as tr, pytest.raises(Stop):
+                ex.run(0, 0, 6, active_fn=lambda r: np.ones(4, bool),
+                       batch_fn=lambda r, plan: {}, on_metrics=stop_at_2)
+        finally:
+            dev.close()
+        drained = [a["round"] for ln, _, _, _, a in tr.spans
+                   if ln == "host/drain"]
+        recorded = [a["round"] for ln, _, _, _, a in tr.spans
+                    if ln == "host/record"]
+        assert drained == [0, 1, 2] and recorded == [0, 1]
 
     def test_summary_registry_backed(self):
         ex = self._run(2)
