@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops, ref
-from repro.models.attention import sdpa_chunked
+from repro.models.attention import sdpa_blockwise
 
 from . import common
 from .common import Row, timed
@@ -63,8 +63,8 @@ def _bench_attention(record):
             lambda q, k, v: ops.flash_attention(q, k, v, causal=True),
             (q, k, v))
         rf, rb = _time_pair(
-            lambda q, k, v: sdpa_chunked(q, k, v, causal=True, window=None,
-                                         logit_cap=None, chunk_q=128),
+            lambda q, k, v: sdpa_blockwise(q, k, v, causal=True, window=None,
+                                           logit_cap=None, chunk_q=128),
             (q, k, v))
         record[name] = {"kernel_fwd_us": kf, "kernel_fwd_bwd_us": kb,
                         "ref_fwd_us": rf, "ref_fwd_bwd_us": rb}

@@ -526,6 +526,12 @@ def make_train_step(cfg: FedStepConfig, par: Parallelism):
                        if k not in SCHEDULE_KEYS}
 
             dev, aux, acts, d_loss = jax.vmap(device_half)(dev, aux, batch_g)
+            # the server half starts once the device half is done: left
+            # free, XLA interleaves the two and keeps the device half's
+            # residuals live through the server forward (+0.34 GB peak at
+            # smollm-135m G4 S2048)
+            dev, aux, acts, d_loss, ring = jax.lax.optimization_barrier(
+                (dev, aux, acts, d_loss, ring))
             train_buf, ring = exchange(acts, batch_g, batch_h, ring)
 
             with jax.named_scope(SERVER_HALF):

@@ -97,12 +97,25 @@ def _decoder_cfg(cfg: ArchConfig) -> ArchConfig:
 # Block application
 # ---------------------------------------------------------------------------
 
+def _attn_out(y, parallelism):
+    """An attention block's output: both attention paths save their
+    output o ("kernel_out"), and y = o @ wo is one small matmul to
+    recompute, so y is saved ("tp_out") only where it took a TP
+    all-reduce that the recompute would issue again."""
+    from jax.ad_checkpoint import checkpoint_name
+    if parallelism is not None and parallelism.mesh.shape.get(
+            parallelism.tp_axis, 1) > 1:
+        return checkpoint_name(y, "tp_out")
+    return y
+
+
 def _apply_block(p: Params, cfg: ArchConfig, mixer: str, ffn: str, h, *,
                  positions, frontend=None, use_kernel=False, parallelism=None,
                  return_state=False):
     """One block.  Mixer/FFN outputs are `checkpoint_name`d "tp_out": with
     the selective remat policy these post-TP-collective tensors are saved,
-    so the backward pass never re-runs the forward all-reduces."""
+    so the backward pass never re-runs the forward all-reduces (attention
+    outputs only where they took one: `_attn_out`)."""
     from jax.ad_checkpoint import checkpoint_name
     aux = jnp.zeros((), jnp.float32)
     state = {}
@@ -113,14 +126,14 @@ def _apply_block(p: Params, cfg: ArchConfig, mixer: str, ffn: str, h, *,
                             return_kv=return_state, parallelism=parallelism)
         if return_state:
             y, state = y
-        h = h + checkpoint_name(y, "tp_out")
+        h = h + _attn_out(y, parallelism)
     elif mixer == "cross":
         y = attention_apply(p["mixer"], cfg.cross_cfg(),
                             rmsnorm_apply(p["ln1"], h), xkv=frontend,
                             return_kv=return_state, parallelism=parallelism)
         if return_state:
             y, state = y
-        h = h + jnp.tanh(p["gate"]) * checkpoint_name(y, "tp_out")
+        h = h + jnp.tanh(p["gate"]) * _attn_out(y, parallelism)
     elif mixer == "mamba":
         y = mamba_apply(p["mixer"], cfg.mamba_cfg(),
                         rmsnorm_apply(p["ln1"], h), use_kernel=use_kernel,
@@ -196,9 +209,10 @@ def _run_stack(blocks, cfg: ArchConfig, h, *, positions, frontend=None,
         # full remat EXCEPT the post-TP-collective block outputs: backward
         # recompute stops at the saved tensors, so the forward's TP
         # all-reduces are never re-issued (collective term / ~1.5).
-        # "kernel_out" additionally saves the Pallas kernels' (o, lse) /
-        # chunk-state residuals — O(S·hd), never the (S×S) scores — so the
-        # custom_vjp backward doesn't re-run the forward kernel either.
+        # "kernel_out" additionally saves the attention paths' (o, lse)
+        # and the SSD kernel's chunk-state residuals — O(S·hd), never the
+        # (S×S) scores — so the custom_vjp backward doesn't re-run the
+        # forward either.
         fn = jax.checkpoint(
             period_fn,
             policy=jax.checkpoint_policies.save_only_these_names(

@@ -45,7 +45,7 @@ from .clock import now as _now
 
 __all__ = [
     "TRACING", "Tracer", "attach", "detach", "traced", "span",
-    "emit_span", "emit_instant", "validate_chrome_trace",
+    "emit_span", "emit_instant", "emit_instant_once", "validate_chrome_trace",
 ]
 
 #: Fast-path guard read by every instrumented call site.
@@ -87,6 +87,16 @@ def emit_span(lane: str, name: str, t0: float, t1: float,
 def emit_instant(lane: str, name: str, t: float, **args) -> None:
     for tr in _STACK:
         tr.add_instant(lane, name, t, **args)
+
+
+def emit_instant_once(lane: str, name: str, t: float, **args) -> None:
+    """``emit_instant`` to each tracer that holds no instant of the same
+    lane, name and args yet (trace-time sites that JAX may trace several
+    times for one call: vmap, remat, custom_vjp rules)."""
+    for tr in _STACK:
+        if not any(i[0] == lane and i[1] == name and i[3] == (args or None)
+                   for i in tr.instants):
+            tr.add_instant(lane, name, t, **args)
 
 
 @contextmanager

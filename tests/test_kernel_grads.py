@@ -58,14 +58,15 @@ def test_flash_attention_grad_parity(shape, kw):
 
 
 def test_flash_attention_grad_matches_sdpa_chunked():
-    """The training fallback (sdpa_chunked) and the kernel agree on grads."""
-    from repro.models.attention import sdpa_chunked
+    """The training path without kernels (sdpa_blockwise, which replaced
+    sdpa_chunked) and the kernel agree on grads."""
+    from repro.models.attention import sdpa_blockwise
     q, k, v = _qkv((2, 96, 96, 8, 2, 32))
     kw = dict(causal=True, window=None, logit_cap=None)
     with ops.kernel_mode(True):
         got = _attn_grads(ops.flash_attention, q, k, v,
                           causal=True)
-    want = _attn_grads(sdpa_chunked, q, k, v, chunk_q=32, **kw)
+    want = _attn_grads(sdpa_blockwise, q, k, v, chunk_q=32, **kw)
     for g, w, name in zip(got, want, "qkv"):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    atol=GTOL, rtol=GTOL, err_msg=f"d{name}")

@@ -80,11 +80,11 @@ def test_flash_attention_property(b, s, heads, hd):
 
 
 def test_jnp_chunked_path_matches_reference():
-    """The jnp fallback (sdpa_chunked) is numerically the oracle too."""
-    from repro.models.attention import sdpa_chunked
+    """The jnp path (sdpa_blockwise) is numerically the oracle too."""
+    from repro.models.attention import sdpa_blockwise
     q, k, v = _qkv((2, 200, 200, 8, 2, 64), jnp.float32)
-    got = sdpa_chunked(q, k, v, causal=True, window=None, logit_cap=None,
-                       chunk_q=64)
+    got = sdpa_blockwise(q, k, v, causal=True, window=None, logit_cap=None,
+                         chunk_q=64)
     want = ref.flash_attention_reference(q, k, v, causal=True)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
