@@ -66,8 +66,9 @@ from repro.parallel.sharding import Parallelism, param_specs, _param_spec, _vali
 
 Params = Any
 
-#: ``jax.named_scope`` names of the round's parts (``repro.obs.scopes``)
-DEVICE_HALF, SERVER_HALF, RING, AGGREGATE = SCOPES
+#: ``jax.named_scope`` names of the round's parts (``repro.obs.scopes``;
+#: the last, ``ssd``, is put on by the Mamba-2 mixer)
+DEVICE_HALF, SERVER_HALF, RING, AGGREGATE = SCOPES[:4]
 
 
 # ---------------------------------------------------------------------------

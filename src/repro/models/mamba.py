@@ -21,6 +21,9 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.obs import trace as _obs
+from repro.obs.clock import now as _now
+
 from .common import dense_init, rmsnorm_apply, rmsnorm_init, silu
 
 Params = Any
@@ -104,6 +107,13 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, initial_state=None):
     body is checkpointed so the (Q, Q) decay matrix is never live across
     chunks; this is the same schedule the Pallas ``ssd`` kernel runs on TPU
     (grid over chunks, state in VMEM).
+
+    Every decay is ``exp`` of a non-positive log decay.  Above the
+    diagonal ``Lcum[t] - Lcum[s]`` is positive (hundreds over a 256-step
+    chunk at the published dt/A ranges), so the mask is applied to the
+    exponent, not to ``exp``'s result: ``where(mask, exp(diff), 0)``
+    would select 0 in the forward pass but give ``0 * inf = NaN`` in the
+    backward one.
     """
     b, T, H, P = x.shape
     G, N = B.shape[2], B.shape[3]
@@ -132,7 +142,7 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, initial_state=None):
         Ltot = Lcum[:, -1, :]                                     # (b,H)
         # intra-chunk quadratic term
         diff = Lcum[:, :, None, :] - Lcum[:, None, :, :]          # (b,Q,Q,H)
-        decay = jnp.where(mask[None, :, :, None], jnp.exp(diff), 0.0)
+        decay = jnp.exp(jnp.where(mask[None, :, :, None], diff, -jnp.inf))
         scores = jnp.einsum("bthn,bshn->btsh", Ch, Bh) * decay
         y = jnp.einsum("btsh,bshp->bthp", scores, xb_c)
         # inter-chunk contribution from the carried state
@@ -167,25 +177,30 @@ def mamba_apply(params: Params, cfg: MambaConfig, x: jnp.ndarray,
     xi = xi.reshape(Bb, T, H, P)
     Bm = Bm.reshape(Bb, T, G, N)
     Cm = Cm.reshape(Bb, T, G, N)
-    if use_kernel and not return_state:
-        from repro.kernels import ops as kops
-        # differentiable (custom_vjp); ops.ssd clamps chunk to T and pads
-        y = kops.ssd(xi, dt, A, Bm, Cm, chunk=cfg.chunk)
-        state = None
-    else:
-        # pad T to a chunk multiple (zero dt => identity decay, zero input)
-        Q = min(cfg.chunk, T)
-        pad = (-T) % Q
-        if pad:
-            xi_p = jnp.pad(xi, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            dt_p = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
-            Bm_p = jnp.pad(Bm, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            Cm_p = jnp.pad(Cm, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            y, h_final = ssd_chunked(xi_p, dt_p, A, Bm_p, Cm_p, Q)
-            y = y[:, :T]
+    Q = min(cfg.chunk, T)
+    pad = (-T) % Q              # T padded to a chunk multiple (zero dt =>
+                                # identity decay, zero input)
+    kernel = use_kernel and not return_state
+    if _obs.TRACING:
+        _obs.emit_instant_once(
+            "host/compile", "ssd_chunks", _now(), shape=[Bb, T, H, P, N],
+            chunk=Q, n_chunks=(T + pad) // Q, pad=pad,
+            path="kernel" if kernel else "jnp")
+    with jax.named_scope("ssd"):
+        if kernel:
+            from repro.kernels import ops as kops
+            # differentiable (custom_vjp); ops.ssd clamps chunk to T and pads
+            y = kops.ssd(xi, dt, A, Bm, Cm, chunk=cfg.chunk)
+            state = None
         else:
-            y, h_final = ssd_chunked(xi, dt, A, Bm, Cm, Q)
-        state = h_final
+            if pad:
+                widen = lambda a: jnp.pad(
+                    a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+                y, state = ssd_chunked(widen(xi), widen(dt), A, widen(Bm),
+                                       widen(Cm), Q)
+                y = y[:, :T]
+            else:
+                y, state = ssd_chunked(xi, dt, A, Bm, Cm, Q)
     y = y + params["D"][None, None, :, None] * xi.astype(jnp.float32)
     y = y.reshape(Bb, T, cfg.d_inner).astype(x.dtype)
     y = rmsnorm_apply(params["norm"], y * silu(z))

@@ -2,11 +2,12 @@
 compiled round's instructions in them.
 
 ``core.fedopt_step.make_train_step`` wraps each part of the round in a
-``jax.named_scope`` from :data:`SCOPES`.  Scopes are metadata only: the
-optimized HLO keeps them in each instruction's ``op_name`` path, and the
-profiler's device events carry the same instruction names, so
-:func:`op_scopes` on the executable's text is what joins a device trace
-to the round's parts.
+``jax.named_scope`` from :data:`SCOPES`, and ``models.mamba.mamba_apply``
+wraps its SSD scan in ``ssd`` inside either half.  Scopes are metadata
+only: the optimized HLO keeps them in each instruction's ``op_name``
+path, and the profiler's device events carry the same instruction names,
+so :func:`op_scopes` on the executable's text is what joins a device
+trace to the round's parts.
 """
 from __future__ import annotations
 
@@ -16,8 +17,10 @@ __all__ = ["SCOPES", "op_scopes", "scope_of"]
 
 #: The round's parts: the vmapped device half with its aux head, the
 #: server half (with its gradient accumulation and update), the ω ring's
-#: read/merge/write, and the staleness-weighted aggregation.
-SCOPES = ("device_half", "server_half", "ring", "aggregate")
+#: read/merge/write, the staleness-weighted aggregation, and the SSD
+#: chunked scan of Mamba-2 layers (innermost, so its ops leave the half
+#: they run in).
+SCOPES = ("device_half", "server_half", "ring", "aggregate", "ssd")
 
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s.*$", re.M)
 _OP_NAME = re.compile(r'\bmetadata=\{[^}]*?\bop_name="((?:[^"\\]|\\.)*)"')

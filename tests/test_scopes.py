@@ -11,7 +11,8 @@ import pytest
 from repro.configs import registry
 from repro.core import fedopt_step as F
 from repro.launch.mesh import make_debug_mesh
-from repro.obs.scopes import SCOPES, op_scopes, scope_of
+from repro.obs.scopes import (_INSTRUCTION, _OP_NAME, SCOPES, op_scopes,
+                               scope_of)
 from repro.obs.trace import Tracer, traced
 
 HLO = """\
@@ -46,7 +47,7 @@ def test_op_scopes_innermost_scope_wins():
 
 def test_op_scopes_names_unscoped_instructions_none():
     table = op_scopes(HLO)
-    # no metadata; an op_name with none of the four scopes; no op_name
+    # no metadata; an op_name with none of the scopes; no op_name
     for name in ("copy-start.2", "add.5", "p0", "p1"):
         assert table[name] is None
     # every instruction, fused ones too, and nothing else
@@ -88,8 +89,8 @@ def _no_persistent_cache():
         cc.reset_cache()
 
 
-def _smoke_round_text():
-    cfg = F.FedStepConfig(arch=registry.smoke_config("smollm-135m"),
+def _smoke_round_text(arch="smollm-135m"):
+    cfg = F.FedStepConfig(arch=registry.smoke_config(arch),
                           l_split=1, n_groups=2, seq_len=16,
                           per_group_batch=4, H=2)
     jitted, state, _, _ = F.jit_train_step(cfg, make_debug_mesh(1, 1))
@@ -140,13 +141,48 @@ def smoke_text():
     return _smoke_round_text()
 
 
-def test_compiled_round_places_every_scope(smoke_text):
-    table = op_scopes(smoke_text)
+@pytest.fixture(scope="module")
+def mamba_text():
+    return _smoke_round_text("mamba2-780m")
+
+
+def _op_names(text):
+    """{instruction name: op_name} of the instructions that have one."""
+    out = {}
+    for m in _INSTRUCTION.finditer(text):
+        op = _OP_NAME.search(m.group(0))
+        if op:
+            out[m.group(1)] = op.group(1)
+    return out
+
+
+@pytest.mark.parametrize("family, want", [
+    # attention: every scope but the SSD scan's
+    ("smoke_text", set(SCOPES) - {"ssd"}),
+    # Mamba-2 (no attention): all of them, the scan among them
+    ("mamba_text", set(SCOPES)),
+])
+def test_compiled_round_places_every_scope(family, want, request):
+    text = request.getfixturevalue(family)
+    table = op_scopes(text)
     placed = set(table.values()) - {None}
-    assert placed == set(SCOPES), placed
-    bearing = _matmul_bearing(smoke_text)
+    assert placed == want, placed
+    bearing = _matmul_bearing(text)
     assert bearing, "no dot-bearing instruction found"
     assert [n for n in bearing if table.get(n) is None] == []
+
+
+def test_ssd_scope_runs_in_both_halves(mamba_text):
+    """The scan's ops are counted under ``ssd`` in the device half (6 of
+    48 layers at full size) and in the server half alike: the innermost
+    scope wins, forward and transposed."""
+    table = op_scopes(mamba_text)
+    names = _op_names(mamba_text)
+    ssd = [names[n] for n, s in table.items() if s == "ssd"]
+    halves = {h for path in ssd for h in ("device_half", "server_half")
+              if h in re.split(r"[/()]", path)}
+    assert halves == {"device_half", "server_half"}, halves
+    assert any("transpose" in path for path in ssd)
 
 
 def test_scopes_are_metadata_only(smoke_text, monkeypatch):
