@@ -103,10 +103,18 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, initial_state=None):
 
     Per chunk: the quadratic intra-chunk term (C_t·B_s masked-decay matmul),
     the inter-chunk contribution from the carried state, and the state
-    update — one ``lax.scan`` over T/Q chunks carrying (b, H, N, P).  The
+    update — one ``lax.scan`` over T/Q chunks carrying the state.  The
     body is checkpointed so the (Q, Q) decay matrix is never live across
     chunks; this is the same schedule the Pallas ``ssd`` kernel runs on TPU
     (grid over chunks, state in VMEM).
+
+    Heads are grouped: head h = g·rep + r reads group g's B and C
+    (H = G·rep).  C·Bᵀ is formed once per group and chunk, and scaled by
+    each head's decay; the (Q, Q) tiles, x and y are head-major,
+    (b·G, rep, Q, ·), so each tile is a matmul operand as it lies.  The
+    two products that contract the state dim, C·h and the state update
+    Bᵀ·x, take a group's heads together, folded into one (rep·P) dim:
+    the carried state is (b·G, N, rep·P).
 
     Every decay is ``exp`` of a non-positive log decay.  Above the
     diagonal ``Lcum[t] - Lcum[s]`` is positive (hundreds over a 256-step
@@ -120,45 +128,56 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, initial_state=None):
     Q = chunk
     nc = T // Q
     rep = H // G
+    # one batch dim for (sequence, group): with G = 1 as a dim of its own,
+    # XLA's CPU simplifier rebuilds the C·Bᵀ dot without its op_name (scope)
+    k = b * G
 
     xb = (x * dt[..., None]).astype(jnp.float32)                  # dt-weighted input
     la = (dt * A[None, None, :]).astype(jnp.float32)              # log decay per step (<0)
 
-    # chunked, scan-major layouts: (nc, b, Q, ...)
-    xb = jnp.moveaxis(xb.reshape(b, nc, Q, H, P), 1, 0)
-    la = jnp.moveaxis(la.reshape(b, nc, Q, H), 1, 0)
-    Bc = jnp.moveaxis(B.reshape(b, nc, Q, G, N).astype(jnp.float32), 1, 0)
-    Cc = jnp.moveaxis(C.reshape(b, nc, Q, G, N).astype(jnp.float32), 1, 0)
+    # chunked, scan-major, head-major layouts: (nc, k, [rep,] Q, ...)
+    xb = xb.reshape(b, nc, Q, G, rep, P).transpose(1, 0, 3, 4, 2, 5)
+    la = la.reshape(b, nc, Q, G, rep).transpose(1, 0, 3, 4, 2)
+    Bc = B.reshape(b, nc, Q, G, N).astype(jnp.float32).transpose(1, 0, 3, 2, 4)
+    Cc = C.reshape(b, nc, Q, G, N).astype(jnp.float32).transpose(1, 0, 3, 2, 4)
+    xb, la, Bc, Cc = (a.reshape(nc, k, *a.shape[3:]) for a in (xb, la, Bc, Cc))
     mask = jnp.tril(jnp.ones((Q, Q), bool))
+
+    def fold(a):        # (k, rep, L, P) -> (k, L, rep·P)
+        return jnp.swapaxes(a, 1, 2).reshape(k, a.shape[2], rep * P)
+
+    def unfold(a):      # (k, L, rep·P) -> (k, rep, L, P)
+        return jnp.swapaxes(a.reshape(k, a.shape[1], rep, P), 1, 2)
 
     h0 = (jnp.zeros((b, H, N, P), jnp.float32) if initial_state is None
           else initial_state.astype(jnp.float32))
+    h0 = fold(h0.reshape(k, rep, N, P))
 
     @jax.checkpoint
     def chunk_fn(h, xb_c, la_c, B_c, C_c):
-        Bh = jnp.repeat(B_c, rep, axis=2)                         # (b,Q,H,N)
-        Ch = jnp.repeat(C_c, rep, axis=2)
-        Lcum = jnp.cumsum(la_c, axis=1)                           # (b,Q,H)
-        Ltot = Lcum[:, -1, :]                                     # (b,H)
+        Lcum = jnp.cumsum(la_c, axis=-1)                          # (k,rep,Q)
+        Ltot = Lcum[..., -1:]                                     # (k,rep,1)
         # intra-chunk quadratic term
-        diff = Lcum[:, :, None, :] - Lcum[:, None, :, :]          # (b,Q,Q,H)
-        decay = jnp.exp(jnp.where(mask[None, :, :, None], diff, -jnp.inf))
-        scores = jnp.einsum("bthn,bshn->btsh", Ch, Bh) * decay
-        y = jnp.einsum("btsh,bshp->bthp", scores, xb_c)
+        diff = Lcum[..., :, None] - Lcum[..., None, :]            # (k,rep,Q,Q)
+        decay = jnp.exp(jnp.where(mask, diff, -jnp.inf))
+        CB = jnp.einsum("ktn,ksn->kts", C_c, B_c)                 # once per group
+        scores = CB[:, None] * decay
+        y = scores @ xb_c                                         # (k,rep,Q,P)
         # inter-chunk contribution from the carried state
-        y = y + jnp.einsum("bthn,bhnp->bthp", Ch * jnp.exp(Lcum)[..., None], h)
+        y = y + jnp.exp(Lcum)[..., None] * unfold(C_c @ h)
         # state update
-        w_state = jnp.exp(Ltot[:, None, :] - Lcum)                # (b,Q,H)
-        S_c = jnp.einsum("bshn,bsh,bshp->bhnp", Bh, w_state, xb_c)
-        h = h * jnp.exp(Ltot)[..., None, None] + S_c
+        w_state = jnp.exp(Ltot - Lcum)[..., None]                 # (k,rep,Q,1)
+        S_c = jnp.swapaxes(B_c, 1, 2) @ fold(w_state * xb_c)      # (k,N,rep·P)
+        h_decay = jnp.broadcast_to(jnp.exp(Ltot), (k, rep, P))
+        h = h * h_decay.reshape(k, 1, rep * P) + S_c
         return h, y
 
     def body(h, inp):
         return chunk_fn(h, *inp)
 
     h_final, ys = jax.lax.scan(body, h0, (xb, la, Bc, Cc))
-    y = jnp.moveaxis(ys, 0, 1).reshape(b, T, H, P)
-    return y, h_final
+    y = ys.reshape(nc, b, G, rep, Q, P).transpose(1, 0, 4, 2, 3, 5)
+    return y.reshape(b, T, H, P), unfold(h_final).reshape(b, H, N, P)
 
 
 def mamba_apply(params: Params, cfg: MambaConfig, x: jnp.ndarray,
@@ -185,7 +204,8 @@ def mamba_apply(params: Params, cfg: MambaConfig, x: jnp.ndarray,
         _obs.emit_instant_once(
             "host/compile", "ssd_chunks", _now(), shape=[Bb, T, H, P, N],
             chunk=Q, n_chunks=(T + pad) // Q, pad=pad,
-            path="kernel" if kernel else "jnp")
+            path="kernel" if kernel else "jnp", groups=G,
+            cb_per_chunk=H if kernel else G)   # the kernel forms C·Bᵀ per head
     with jax.named_scope("ssd"):
         if kernel:
             from repro.kernels import ops as kops
