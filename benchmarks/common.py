@@ -3,13 +3,12 @@
 Testbed A: 8 devices (Raspberry Pi classes, 4 speed groups), CPU server,
 50 Mbps links.  Testbed B: 16 devices (Jetson classes), GPU server,
 100 Mbps links.  Speed ratios follow Table 3; absolute scales are nominal
-(the figures reproduce *relative* orderings — see DESIGN.md §7).
+(the figures reproduce *relative* orderings, not absolute times).
 
 Every ``BENCH_*.json`` record should be written through
 :func:`write_record`, which stamps an ``env`` block (backend, device
-kind, jax/numpy versions, interpret-mode flag, smoke flag) so numbers
-like the kernel suite's cpu-interpret timings are self-describing
-instead of relying on out-of-band knowledge of where they ran."""
+kind, jax/numpy versions, interpret-mode flag, smoke flag) so every
+record says where it ran instead of relying on out-of-band knowledge."""
 from __future__ import annotations
 
 import json

@@ -1,5 +1,4 @@
-"""Benchmark harness: one module per paper table/figure + the roofline
-table.  Prints ``name,us_per_call,derived`` CSV.
+"""Benchmark harness: one module per paper table/figure.  Prints ``name,us_per_call,derived`` CSV.
 
     PYTHONPATH=src python -m benchmarks.run              # all
     PYTHONPATH=src python -m benchmarks.run idle comm    # subset
@@ -18,8 +17,8 @@ import sys
 
 from . import (bench_ablation_aux, bench_ablation_sched, bench_accuracy,
                bench_communication, bench_faults, bench_fleet, bench_idle,
-               bench_kernels, bench_memory, bench_partition,
-               bench_resilience, bench_roofline, bench_throughput, common)
+               bench_memory, bench_partition, bench_resilience,
+               bench_throughput, common)
 
 SUITES = {
     "communication": bench_communication,   # Fig. 2
@@ -31,8 +30,6 @@ SUITES = {
     "ablation_aux": bench_ablation_aux,     # Fig. 14
     "ablation_sched": bench_ablation_sched, # Fig. 15
     "partition": bench_partition,           # Eq. 6-8
-    "roofline": bench_roofline,             # §Roofline (deliverable g)
-    "kernels": bench_kernels,               # Pallas fwd/bwd vs references
     "fleet": bench_fleet,                   # shared-trace scenario compare
     "faults": bench_faults,                 # chaos plane: goodput under faults
 }

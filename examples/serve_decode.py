@@ -1,9 +1,8 @@
 """Scenario: batched serving with the merged global model.
 
 After FedOptima training, device + server halves merge into one model
-(``merge_params``); serving is standard prefill + KV-cache decode — the
-same code paths the decode_32k / long_500k dry-run cells lower at pod
-scale.  Demonstrates a hybrid arch (jamba: mamba states + attention KV +
+(``merge_params``); serving is standard prefill + KV-cache decode
+(``repro.launch.serve``).  Demonstrates a hybrid arch (jamba: mamba states + attention KV +
 MoE routing in one cache pytree).
 
 Run:  PYTHONPATH=src python examples/serve_decode.py [--arch jamba-1.5-large-398b]

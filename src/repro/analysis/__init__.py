@@ -1,31 +1,18 @@
-"""Analysis layer: HLO/roofline cost models + static analysis tooling.
+"""Analysis layer: the protocol sanitizer and the lint CLI.
 
-Re-exports are lazy: ``hlo``/``roofline`` pull in jax, but the
-``sanitize`` instrumentation hooks live on hot control-plane paths and
-the ``lint`` CLI must start fast — importing this package must stay
-cheap (stdlib only) so ``from repro.analysis import sanitize`` inside
-``repro.core`` neither costs a jax import nor creates a cycle.
+Re-exports are lazy: the ``sanitize`` instrumentation hooks live on hot
+control-plane paths and the ``lint`` CLI must start fast — importing
+this package must stay cheap (stdlib only) so ``from repro.analysis
+import sanitize`` inside ``repro.core`` neither costs a jax import nor
+creates a cycle.
 """
 from __future__ import annotations
 
-_HLO = ("Cost", "HloModule", "analyze_compiled", "analyze_text")
-_ROOFLINE = ("RooflineTerms", "count_params", "model_flops", "roofline",
-             "PEAK_FLOPS", "HBM_BW", "LINK_BW")
-
-__all__ = [*_HLO, *_ROOFLINE, "hlo", "roofline", "sanitize", "lint"]
+__all__ = ["sanitize", "lint"]
 
 
 def __getattr__(name: str):
     import importlib
-    if name in ("hlo", "sanitize", "lint"):
+    if name in __all__:
         return importlib.import_module(f"repro.analysis.{name}")
-    if name in _HLO or name in _ROOFLINE:
-        sub = "hlo" if name in _HLO else "roofline"
-        mod = importlib.import_module(f"repro.analysis.{sub}")
-        val = getattr(mod, name)
-        # pin the resolved attribute: the submodule import just rebound
-        # ``roofline`` on this package to the MODULE, but the seed API
-        # exported the roofline() function under that name
-        globals()[name] = val
-        return val
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -1,32 +1,13 @@
 """Architecture registry: --arch <id> resolves here.
 
-Each assigned architecture is an ArchConfig (full size, exercised only via
-the dry-run) plus a smoke_config() reduction (same family/pattern, tiny
-dims, runnable on CPU).  Shapes are the assignment's four cells.
+Each architecture is an ArchConfig at its published widths (``--full``;
+the benchmark's configurations run these on the chip) plus a
+smoke_config() reduction (same family/pattern, tiny dims, runnable on
+CPU).
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
-
-import jax.numpy as jnp
-
 from repro.models.api import ArchConfig
-
-
-@dataclass(frozen=True)
-class ShapeCfg:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str            # "train" | "prefill" | "decode"
-
-
-SHAPES = {
-    "train_4k": ShapeCfg("train_4k", 4096, 256, "train"),
-    "prefill_32k": ShapeCfg("prefill_32k", 32768, 32, "prefill"),
-    "decode_32k": ShapeCfg("decode_32k", 32768, 128, "decode"),
-    "long_500k": ShapeCfg("long_500k", 524288, 1, "decode"),
-}
 
 
 ARCHS: dict[str, ArchConfig] = {}
@@ -79,18 +60,3 @@ def get(name: str) -> ArchConfig:
     if name not in ARCHS:
         raise KeyError(f"unknown arch '{name}'; known: {sorted(ARCHS)}")
     return ARCHS[name]
-
-
-def skip_reason(arch_name: str, shape_name: str) -> str | None:
-    """Why an (arch × shape) cell is skipped (None = runnable)."""
-    cfg = ARCHS[arch_name]
-    if shape_name == "long_500k" and not cfg.long_context_ok:
-        return ("full-attention arch: long_500k requires a sub-quadratic "
-                "mixer (see DESIGN.md §Arch-applicability)")
-    return None
-
-
-def cells():
-    """All assigned (arch × shape) cells, with skip annotations."""
-    return [(name, sname, skip_reason(name, sname))
-            for name in ARCHS for sname in SHAPES]

@@ -1,20 +1,19 @@
 """Datacenter-scale FedOptima: the paper's split-training pipeline as one
 pjit program per (arch × shape × mesh).
 
-Mapping (DESIGN.md §3): an FL "device" becomes a *device group* — one index
-of the mesh's data-parallel axes (pod × data), owning a ``model``-axis (TP)
-slice of chips.  Each group trains its own copy of the device-side block
-(params stacked on a leading group axis, sharded over dp) on its local
-non-IID shard, with gradients from the *auxiliary network* only — no
+Mapping: an FL "device" becomes a *device group* — one index of the
+mesh's data-parallel axes (pod × data), owning a ``model``-axis (TP) slice
+of chips.  Each group trains its own copy of the device-side block (params
+stacked on a leading group axis, sharded over dp) on its local non-IID
+shard, with gradients from the *auxiliary network* only — no
 gradient ever flows server→device (``stop_gradient`` on the activation
 hand-off).  The server-side block is ONE centrally-trained model (TP over
 ``model``, FSDP over dp) consuming the activation stream.
 
-Idle-time elimination carries over: with ``pipeline_acts=True`` (the
-paper's queue semantics) the server trains on *previously scheduled*
-activations, so the device half and the server half of the XLA program
-have no data dependency — the latency-hiding scheduler overlaps them,
-which is Fig. 1(d) at pod scale.
+Idle-time elimination carries over: the server trains on *previously
+scheduled* activations (the paper's queue semantics), so the device half
+and the server half of the XLA program have no data dependency, which is
+Fig. 1(d) at pod scale.
 
 The activation hand-off is an ω-deep ring of scheduled batches (Eq. 3's
 bounded buffer realized on-mesh): ``state["act_buf"]`` holds ω slots, each
@@ -49,8 +48,7 @@ Structure of one hybrid step::
 from __future__ import annotations
 
 import zlib
-from dataclasses import dataclass, field, replace
-from functools import partial
+from dataclasses import dataclass, replace
 from typing import Any
 
 import jax
@@ -89,14 +87,12 @@ class FedStepConfig:
     lr_s: float = 0.05
     server_opt: str = "sgd"           # paper Alg. 4 line 10 (adamw optional)
     param_dtype: Any = jnp.float32
-    # --- pipeline/perf options (see EXPERIMENTS.md §Perf) ---
-    pipeline_acts: bool = True        # server trains on prev-step activations
     omega: int = 1                    # activation-ring depth in scheduled
                                       # batches (Eq. 3 cap ω); slots are
                                       # read/written per the host schedule
-    remat: Any = "selective"          # True | False | "selective" (§Perf it.4:
-                                      # save post-TP-collective outputs only)
-    act_sharding: str = "seq"         # "seq" (Megatron-SP carries) | "none"
+    remat: Any = "selective"          # True | False | "selective" (save
+                                      # post-TP-collective outputs only);
+                                      # not timed on the chip
     use_kernel: bool = False          # Pallas kernels for attn/SSD hot spots
                                       # (differentiable: custom_vjp backward
                                       # kernels, so both halves' value_and_grad
@@ -104,25 +100,6 @@ class FedStepConfig:
                                       # with remat="selective", which saves
                                       # the kernels' (o, lse)/state residuals)
     agg_compress: bool = False        # int8 aggregation payload (cross-pod)
-    # Server gradient accumulation: apply the server optimizer once per
-    # round (grads summed over the H scheduled batches) instead of per
-    # batch (Alg. 4 line 10).  Keeps θ_s loop-invariant inside the round
-    # scan, so the FSDP weight all-gathers hoist out of the H-loop —
-    # collective traffic / H.  A beyond-paper systems trade-off: same data,
-    # one optimizer step per round.
-    server_accum: bool = False
-    ep_interior: bool = False         # pin MoE expert tensors to EP axis
-                                      # (§Perf it.6: refuted — forces
-                                      # redundant compute under GSPMD)
-    # Explicit shard_map expert parallelism for the server block: each
-    # ``model`` shard routes its dp-shard's tokens to its LOCAL experts and
-    # partial outputs psum over ``model``.  Avoids GSPMD's unsharded
-    # gather/scatter dispatch tables (the MoE cells' dominant traffic).
-    ep_shard_map: bool = True         # (§Perf it.7: 7x on MoE cells)
-
-    @property
-    def seq_shard_acts(self) -> bool:
-        return self.act_sharding == "seq"
 
     @property
     def global_batch(self) -> int:
@@ -170,17 +147,15 @@ def init_train_state(rng, cfg: FedStepConfig) -> Params:
     stack = lambda t: jax.tree.map(
         lambda x: jnp.broadcast_to(x[None], (G,) + x.shape), t)
     s_init, _ = make_optimizer(cfg.server_opt)
-    state = {
+    return {
         "dev": stack(dev1),
         "aux": stack(aux1),
         "srv": srv,
         "srv_opt": s_init(srv),
         "step": jnp.zeros((), jnp.int32),
         "version": jnp.zeros((), jnp.int32),
+        "act_buf": _empty_act_buf(cfg),
     }
-    if cfg.pipeline_acts:
-        state["act_buf"] = _empty_act_buf(cfg)
-    return state
 
 
 def _empty_act_slot(cfg: FedStepConfig) -> Params:
@@ -211,7 +186,7 @@ def _empty_act_buf(cfg: FedStepConfig) -> Params:
 
 
 def abstract_train_state(cfg: FedStepConfig) -> Params:
-    """ShapeDtypeStruct state — no allocation (dry-run path)."""
+    """ShapeDtypeStruct state — no allocation."""
     return jax.eval_shape(lambda: init_train_state(jax.random.PRNGKey(0), cfg))
 
 
@@ -307,10 +282,11 @@ def _stacked_specs(params: Params, par: Parallelism) -> Params:
     return jax.tree_util.tree_unflatten(treedef, specs)
 
 
-def _act_buf_specs(buf: Params, par: Parallelism, seq_shard: bool,
-                   ring: bool = False) -> Params:
-    """Slot-shaped activation specs; ``ring=True`` for the ω-stacked state
-    buffer (leading slot axis replicated, inner dims as one slot)."""
+def _act_buf_specs(buf: Params, par: Parallelism, ring: bool = False) -> Params:
+    """Slot-shaped activation specs: batch over dp, the sequence of (B, S, D)
+    activations over ``model`` (Megatron-SP); ``ring=True`` for the
+    ω-stacked state buffer (leading slot axis replicated, inner dims as one
+    slot)."""
     dp = tuple(par.dp_axes)
     tp = par.tp_axis
     tp_size = par.mesh.shape[tp]
@@ -319,7 +295,7 @@ def _act_buf_specs(buf: Params, par: Parallelism, seq_shard: bool,
         shape = leaf.shape[1:] if ring else leaf.shape
         b = dp if shape[0] % par.dp_size == 0 else None
         if len(shape) == 3:     # (B, S, D) or (B, F, D)
-            s = tp if (seq_shard and shape[1] % tp_size == 0) else None
+            s = tp if shape[1] % tp_size == 0 else None
             inner = (b, s, None)
         elif len(shape) == 2:
             inner = (b, None)   # (B, S) int labels/tokens
@@ -329,22 +305,20 @@ def _act_buf_specs(buf: Params, par: Parallelism, seq_shard: bool,
     return {k: spec(k, v) for k, v in buf.items()}
 
 
-def state_specs(state: Params, cfg: FedStepConfig, par: Parallelism) -> Params:
+def state_specs(state: Params, par: Parallelism) -> Params:
     specs = {
         "dev": _stacked_specs(state["dev"], par),
         "aux": _stacked_specs(state["aux"], par),
         "srv": param_specs(state["srv"], par),
         "step": P(),
         "version": P(),
+        "act_buf": _act_buf_specs(state["act_buf"], par, ring=True),
     }
     # optimizer state mirrors its parameters (ZeRO); scalars replicated
     so = {}
     for k, v in state["srv_opt"].items():
         so[k] = specs["srv"] if k in ("mu", "nu", "velocity") else P()
     specs["srv_opt"] = so
-    if "act_buf" in state:
-        specs["act_buf"] = _act_buf_specs(state["act_buf"], par,
-                                          cfg.seq_shard_acts, ring=True)
     return specs
 
 
@@ -383,17 +357,15 @@ def make_train_step(cfg: FedStepConfig, par: Parallelism):
     Micro-iterating also bounds activation memory to one iteration's worth.
     """
     arch = cfg.arch
-    s_init, s_update = make_optimizer(cfg.server_opt)
-    # Activation-sharding policy.  Inside the vmapped device half the group
-    # axis has consumed dp, so act_batch=None there; the server half (not
-    # vmapped) shards batch over dp.  "seq" adds Megatron-SP carries.
-    constraints = cfg.act_sharding != "none"
-    seq = cfg.act_sharding == "seq"
-    dev_par = replace(par, ep=False, constraints=constraints, seq_shard=seq,
-                      act_batch=None, moe_interior=cfg.ep_interior)
-    srv_par = replace(par, ep=cfg.ep_shard_map, constraints=constraints,
-                      seq_shard=seq, act_batch=tuple(par.dp_axes),
-                      moe_interior=cfg.ep_interior)
+    _, s_update = make_optimizer(cfg.server_opt)
+    # Activation sharding: Megatron-SP carries in both halves.  Inside the
+    # vmapped device half the group axis has consumed dp, so act_batch=None
+    # there; the server half (not vmapped) shards batch over dp, and routes
+    # MoE tokens to each ``model`` shard's local experts (shard_map EP).
+    dev_par = replace(par, ep=False, constraints=True, seq_shard=True,
+                      act_batch=None)
+    srv_par = replace(par, ep=True, constraints=True, seq_shard=True,
+                      act_batch=tuple(par.dp_axes))
     kw = dict(use_kernel=cfg.use_kernel, remat=cfg.remat)
 
     @jax.named_scope(DEVICE_HALF)
@@ -420,9 +392,9 @@ def make_train_step(cfg: FedStepConfig, par: Parallelism):
                            aux, ga)
         return dev, aux, acts, d_loss
 
-    def server_grads(srv, buf):
-        """One server iteration's loss + grads on the scheduled activation
-        batch (Alg. 4 lines 5-9) — the single global model, never stale."""
+    def server_half(srv, srv_opt, buf):
+        """One server iteration on the scheduled activation batch (Alg. 4
+        lines 5-10) — the single global model, never stale."""
         def loss_fn(s):
             if arch.n_decoder_layers:
                 return tfm.server_encdec_loss(s, arch, buf["acts"],
@@ -434,11 +406,7 @@ def make_train_step(cfg: FedStepConfig, par: Parallelism):
                                            frontend=buf.get("frontend"),
                                            parallelism=srv_par,
                                            row_mask=buf["valid"], **kw)
-        return jax.value_and_grad(loss_fn)(srv)
-
-    def server_half(srv, srv_opt, buf):
-        """Per-batch server SGD (Alg. 4 line 10)."""
-        s_loss, gs = server_grads(srv, buf)
+        s_loss, gs = jax.value_and_grad(loss_fn)(srv)
         srv, srv_opt = s_update(srv, gs, srv_opt, cfg.lr_s)
         return srv, srv_opt, s_loss
 
@@ -480,49 +448,38 @@ def make_train_step(cfg: FedStepConfig, par: Parallelism):
         if arch.family == "vlm":
             new_buf["frontend"] = batch_g["frontend"].reshape(
                 (G * b,) + batch_g["frontend"].shape[2:])
-        if cfg.seq_shard_acts:
-            spec = _act_buf_specs({"acts": new_buf["acts"]}, par,
-                                  True)["acts"]
-            new_buf["acts"] = jax.lax.with_sharding_constraint(
-                new_buf["acts"], NamedSharding(par.mesh, spec))
+        spec = _act_buf_specs({"acts": new_buf["acts"]}, par)["acts"]
+        new_buf["acts"] = jax.lax.with_sharding_constraint(
+            new_buf["acts"], NamedSharding(par.mesh, spec))
 
-        if cfg.pipeline_acts:
-            # server consumes the host-scheduled slot (ring state from
-            # BEFORE this iteration's write, matching the control
-            # plane's read-then-write bookkeeping) ...
-            read_slot = batch_h["read_slot"]
-            train_buf = jax.tree.map(
-                lambda x: jax.lax.dynamic_index_in_dim(
-                    x, read_slot, 0, keepdims=False), ring)
-            # ... while token-holding groups' rows refresh the written
-            # slot; groups without a flow-control grant keep the slot's
-            # previous content (their emission is not shipped)
-            write_slot = batch_h["write_slot"]
-            keep = batch_h["send_mask"] > 0.5            # (G,)
-            rows = jnp.repeat(keep, b)                   # (G*b,) grouped
-            old = jax.tree.map(
-                lambda x: jax.lax.dynamic_index_in_dim(
-                    x, write_slot, 0, keepdims=False), ring)
-            merged = jax.tree.map(
-                lambda n, o: jnp.where(
-                    rows.reshape((-1,) + (1,) * (n.ndim - 1)), n, o),
-                new_buf, old)
-            ring = jax.tree.map(
-                lambda r, m: jax.lax.dynamic_update_index_in_dim(
-                    r, m, write_slot, 0), ring, merged)
-        else:
-            train_buf = new_buf
+        # server consumes the host-scheduled slot (ring state from BEFORE
+        # this iteration's write, matching the control plane's
+        # read-then-write bookkeeping) ...
+        read_slot = batch_h["read_slot"]
+        train_buf = jax.tree.map(
+            lambda x: jax.lax.dynamic_index_in_dim(
+                x, read_slot, 0, keepdims=False), ring)
+        # ... while token-holding groups' rows refresh the written slot;
+        # groups without a flow-control grant keep the slot's previous
+        # content (their emission is not shipped)
+        write_slot = batch_h["write_slot"]
+        keep = batch_h["send_mask"] > 0.5            # (G,)
+        rows = jnp.repeat(keep, b)                   # (G*b,) grouped
+        old = jax.tree.map(
+            lambda x: jax.lax.dynamic_index_in_dim(
+                x, write_slot, 0, keepdims=False), ring)
+        merged = jax.tree.map(
+            lambda n, o: jnp.where(
+                rows.reshape((-1,) + (1,) * (n.ndim - 1)), n, o),
+            new_buf, old)
+        ring = jax.tree.map(
+            lambda r, m: jax.lax.dynamic_update_index_in_dim(
+                r, m, write_slot, 0), ring, merged)
         return train_buf, ring
 
     def step(state, batch):
-        srv_const = state["srv"] if cfg.server_accum else None
-
         def body(carry, batch_h):
-            if cfg.server_accum:
-                dev, aux, srv_acc, *rest = carry
-            else:
-                dev, aux, srv, srv_opt, *rest = carry
-            ring = rest[0] if cfg.pipeline_acts else None
+            dev, aux, srv, srv_opt, ring = carry
             batch_g = {k: v for k, v in batch_h.items()
                        if k not in SCHEDULE_KEYS}
 
@@ -536,56 +493,28 @@ def make_train_step(cfg: FedStepConfig, par: Parallelism):
             train_buf, ring = exchange(acts, batch_g, batch_h, ring)
 
             with jax.named_scope(SERVER_HALF):
-                if cfg.server_accum:
-                    # θ_s loop-invariant: grads accumulate, FSDP gathers
-                    # hoist
-                    s_loss, gs = server_grads(srv_const, train_buf)
-                    srv_acc = jax.tree.map(
-                        lambda a, g: a + g.astype(jnp.float32), srv_acc, gs)
-                    carry = (dev, aux, srv_acc)
-                else:
-                    srv, srv_opt, s_loss = server_half(srv, srv_opt,
-                                                       train_buf)
-                    carry = (dev, aux, srv, srv_opt)
-            if cfg.pipeline_acts:
-                carry = carry + (ring,)
+                srv, srv_opt, s_loss = server_half(srv, srv_opt, train_buf)
             s_live = jnp.any(train_buf["valid"] > 0).astype(jnp.float32)
-            return carry, (jnp.mean(d_loss), s_loss, s_live)
+            return (dev, aux, srv, srv_opt, ring), \
+                (jnp.mean(d_loss), s_loss, s_live)
 
         # (G, H, ...) -> scan-major (H, G, ...); the schedule fields already
         # carry H on the leading axis and pass through unchanged; the
         # per-group (G,) control fields are consumed once after the scan
         xs = {k: v if k in SCHEDULE_KEYS else jnp.moveaxis(v, 1, 0)
               for k, v in batch.items() if k not in PER_GROUP_KEYS}
-        if cfg.server_accum:
-            zeros = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), state["srv"])
-            carry = (state["dev"], state["aux"], zeros)
-        else:
-            carry = (state["dev"], state["aux"], state["srv"],
-                     state["srv_opt"])
-        if cfg.pipeline_acts:
-            carry = carry + (state["act_buf"],)
+        carry = (state["dev"], state["aux"], state["srv"], state["srv_opt"],
+                 state["act_buf"])
         carry, (d_losses, s_losses, s_live) = jax.lax.scan(body, carry, xs)
-        if cfg.server_accum:
-            dev, aux, srv_acc = carry[:3]
-            with jax.named_scope(SERVER_HALF):
-                gs = jax.tree.map(lambda a, p: (a / cfg.H).astype(p.dtype),
-                                  srv_acc, state["srv"])
-                srv, srv_opt = s_update(state["srv"], gs, state["srv_opt"],
-                                        cfg.lr_s)
-        else:
-            dev, aux, srv, srv_opt = carry[:4]
+        dev, aux, srv, srv_opt, ring = carry
 
         # ---- end-of-round async aggregation (Alg. 1 l.13, Alg. 4 l.12-19)
         dev, aux = aggregate((dev, aux), batch["agg_weight"],
                              batch["bcast_mask"])
 
         new_state = dict(state, dev=dev, aux=aux, srv=srv, srv_opt=srv_opt,
-                         step=state["step"] + 1,
+                         act_buf=ring, step=state["step"] + 1,
                          version=state["version"] + 1)
-        if cfg.pipeline_acts:
-            new_state["act_buf"] = carry[-1]
         # s_loss: mean over the micro-iterations whose read held any row
         metrics = {"d_loss": jnp.mean(d_losses),
                    "s_loss": jnp.sum(s_losses * s_live)
@@ -622,7 +551,7 @@ def jit_train_step(cfg: FedStepConfig, mesh, *, donate: bool = True):
     par = Parallelism(mesh=mesh, dp_axes=dp)
     step = make_train_step(cfg, par)
     state = abstract_train_state(cfg)
-    s_spec = to_named(state_specs(state, cfg, par), mesh)
+    s_spec = to_named(state_specs(state, par), mesh)
     b_spec = to_named(batch_specs(cfg, par), mesh)
     m_spec = {"d_loss": NamedSharding(mesh, P()),
               "s_loss": NamedSharding(mesh, P())}
@@ -721,111 +650,3 @@ def scatter_group_state(state: Params, g: int, retained: dict,
         spec = None if state_shardings is None else state_shardings[key]
         new[key] = put(state[key], retained[key], spec)
     return new
-
-
-# ---------------------------------------------------------------------------
-# Serving steps (prefill / decode) — single merged global model
-# ---------------------------------------------------------------------------
-
-def serve_param_specs(params: Params, par: Parallelism) -> Params:
-    return param_specs(params, par)
-
-
-def _cache_specs(caches, par: Parallelism) -> list:
-    """Decode caches: batch over dp when divisible; the long axis (KV slots
-    for attention, heads for SSM states) over ``model``.  KV-slot sharding
-    is the flash-decoding layout — each model shard scores its slice of the
-    context and the partial softmax reduces over ``model``."""
-    dp = tuple(par.dp_axes)
-    tp = par.tp_axis
-    dp_size = par.dp_size
-    tp_size = par.mesh.shape[tp]
-
-    def spec_leaf(path_key: str, leaf):
-        # leaves are stacked (n_periods, B, ...)
-        s = [None] * leaf.ndim
-        if leaf.ndim >= 2 and leaf.shape[1] % dp_size == 0:
-            s[1] = dp
-        if "conv" in path_key:                      # (n, B, K-1, Cd)
-            if leaf.ndim == 4 and leaf.shape[3] % tp_size == 0:
-                s[3] = tp
-        elif "ssm" in path_key:                     # (n, B, H, N, P)
-            if leaf.ndim == 5 and leaf.shape[2] % tp_size == 0:
-                s[2] = tp
-        elif leaf.ndim >= 3 and leaf.shape[2] % tp_size == 0:
-            s[2] = tp                               # (n, B, L, Hkv, hd): L
-        return P(*s)
-
-    flat, treedef = jax.tree_util.tree_flatten_with_path(caches)
-    specs = []
-    for path, leaf in flat:
-        key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))
-                       for p in path)
-        specs.append(spec_leaf(key, leaf))
-    return jax.tree_util.tree_unflatten(treedef, specs)
-
-
-def jit_prefill(arch: ArchConfig, mesh, *, batch: int, seq_len: int,
-                param_dtype=jnp.float32, use_kernel: bool = False,
-                seq_shard: bool = True):
-    """Lowerable prefill: tokens (B, S) -> (last logits, primed caches)."""
-    dp = tuple(a for a in mesh.axis_names if a != "model")
-    par = Parallelism(mesh=mesh, dp_axes=dp)
-    b_div = batch % par.dp_size == 0
-    # ep=b_div: prefill MoE layers use shard_map expert parallelism too
-    # (§Perf it.7 — GSPMD materialises unsharded dispatch tables otherwise)
-    run_par = replace(par, ep=b_div, constraints=True, seq_shard=seq_shard,
-                      act_batch=dp if b_div else None, moe_interior=False)
-    sds = jax.ShapeDtypeStruct
-
-    params = jax.eval_shape(
-        lambda: tfm.init_params(jax.random.PRNGKey(0), arch, param_dtype))
-    p_spec = to_named(param_specs(params, par), mesh)
-    tokens = sds((batch, seq_len), jnp.int32)
-    t_spec = NamedSharding(mesh, P(dp if batch % par.dp_size == 0 else None,
-                                   None))
-    args = [params, tokens]
-    in_shardings = [p_spec, t_spec]
-    if arch.frontend_len:
-        args.append(sds((batch, arch.frontend_len, arch.d_model),
-                        param_dtype))
-        in_shardings.append(NamedSharding(
-            mesh, P(dp if batch % par.dp_size == 0 else None, None, None)))
-
-    def prefill_fn(params, tokens, frontend=None):
-        return tfm.prefill(params, arch, tokens, max_len=seq_len,
-                           frontend=frontend, use_kernel=use_kernel,
-                           parallelism=run_par, remat=True)
-
-    jitted = jax.jit(prefill_fn, in_shardings=tuple(in_shardings))
-    return jitted, tuple(args)
-
-
-def jit_decode(arch: ArchConfig, mesh, *, batch: int, cache_len: int,
-               param_dtype=jnp.float32):
-    """Lowerable decode: one new token against a KV cache of ``cache_len``."""
-    dp = tuple(a for a in mesh.axis_names if a != "model")
-    par = Parallelism(mesh=mesh, dp_axes=dp)
-    sds = jax.ShapeDtypeStruct
-
-    params = jax.eval_shape(
-        lambda: tfm.init_params(jax.random.PRNGKey(0), arch, param_dtype))
-    caches = jax.eval_shape(
-        lambda: tfm.init_serve_state(arch, batch, cache_len, param_dtype))
-    p_spec = to_named(param_specs(params, par), mesh)
-    c_spec = to_named(_cache_specs(caches, par), mesh)
-    b_ok = batch % par.dp_size == 0
-    tok_spec = NamedSharding(mesh, P(dp if b_ok else None, None))
-
-    def decode_fn(params, caches, token, position):
-        return tfm.serve_decode_step(params, arch, caches, token, position)
-
-    jitted = jax.jit(
-        decode_fn,
-        in_shardings=(p_spec, c_spec, tok_spec, NamedSharding(mesh, P())),
-        out_shardings=(NamedSharding(mesh, P(dp if b_ok else None, None)),
-                       c_spec),
-        donate_argnums=(1,))
-    args = (params, caches, sds((batch, 1), jnp.int32),
-            sds((), jnp.int32))
-    return jitted, args

@@ -1,18 +1,10 @@
-"""Production mesh factories.
+"""Mesh factories for the pod path.
 
-``make_production_mesh`` is a FUNCTION (not a module-level constant) so
-importing this module never touches jax device state — the dry-run sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before *any* jax
-import, and everything else must see the real single CPU device.
-
-Mesh layout (TPU v5e pods):
-  single-pod : (data=16, model=16)             = 256 chips
-  multi-pod  : (pod=2, data=16, model=16)      = 512 chips
-
-FedOptima mapping: one FL "device group" per (pod, data) index — 16 groups
-single-pod, 32 groups multi-pod — each group owning a 16-chip ``model``
-(TP) slice; the server-side block is trained centrally across the whole
-mesh (DP over pod×data, TP over model).
+A mesh is ``(data, model)``, or ``(pod, data, model)``: one FL "device
+group" per (pod, data) index, each owning a ``model`` (TP) slice; the
+server-side block is trained centrally across the whole mesh (DP over
+pod×data, TP over model).  The factories are functions, so importing
+this module never touches jax device state.
 """
 from __future__ import annotations
 
@@ -26,12 +18,6 @@ def _auto_mesh(shape: tuple, axes: tuple):
     ``Explicit`` axes would type-check shardings through the vmap over FL
     groups and refuse it."""
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 2, *, pod: int = 0):

@@ -2,8 +2,8 @@
 
 FedOptima is a *training* system; serving uses the merged (device+server)
 model — ``transformer.merge_params`` — behind the standard prefill/decode
-steps that the decode/long dry-run cells lower.  This driver demonstrates
-batched request serving end-to-end on CPU with a smoke-scale arch::
+steps.  This driver demonstrates batched request serving end-to-end on
+CPU with a smoke-scale arch::
 
     python -m repro.launch.serve --arch smollm-135m --batch 4 --new-tokens 16
 """

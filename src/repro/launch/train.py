@@ -244,13 +244,12 @@ def run_pod(args) -> dict:
     if verified_step is not None:
         start_round = verified_step
         state = store.restore(args.ckpt_dir, start_round, like)
-        if "act_buf" in state:
-            ring = jax.tree.leaves(state["act_buf"])[0].shape[0]
-            if ring != omega:
-                raise ValueError(
-                    f"checkpoint has an ω={ring} activation ring but "
-                    f"--omega={omega}; out-of-range slot indices would be "
-                    f"silently clamped — restart with --omega {ring}")
+        ring = jax.tree.leaves(state["act_buf"])[0].shape[0]
+        if ring != omega:
+            raise ValueError(
+                f"checkpoint has an ω={ring} activation ring but "
+                f"--omega={omega}; out-of-range slot indices would be "
+                f"silently clamped — restart with --omega {ring}")
         meta = store.restore_metadata(args.ckpt_dir, start_round)
         if "control_plane" in meta:
             # restore the host plan with the ring it describes, or slot
@@ -665,8 +664,7 @@ def build_parser() -> argparse.ArgumentParser:
                    help="staleness cap D for aggregation (Alg. 4)")
     p.add_argument("--use-kernel", action="store_true",
                    help="run attention/SSD through the fused Pallas kernels "
-                        "(differentiable; interpret mode on CPU — see "
-                        "EXPERIMENTS.md §Perf)")
+                        "(differentiable; interpret mode on CPU)")
     p.add_argument("--mesh-data", type=int, default=1)
     p.add_argument("--mesh-model", type=int, default=1)
     p.add_argument("--groups-per-shard", type=int, default=4)

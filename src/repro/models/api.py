@@ -100,11 +100,3 @@ class ArchConfig:
     def scaled(self, **kw) -> "ArchConfig":
         """Reduced copy for smoke tests."""
         return replace(self, **kw)
-
-    @property
-    def long_context_ok(self) -> bool:
-        """True when the arch runs the long_500k cell: SSM/hybrid families
-        (state-space layers carry the context; the few attention layers in a
-        hybrid hold an O(T) KV cache at batch 1, which is fine for decode).
-        Pure full-attention archs are skipped per the assignment brief."""
-        return self.family in ("ssm", "hybrid")

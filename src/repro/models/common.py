@@ -8,7 +8,7 @@ Every block follows the convention::
 
 Weights that repeat across layers are *stacked* on a leading axis so the
 forward pass can ``jax.lax.scan`` over them — this keeps compiled HLO size
-independent of depth (critical for 64–100 layer dry-runs).
+independent of depth.
 """
 from __future__ import annotations
 

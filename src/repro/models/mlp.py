@@ -152,8 +152,7 @@ def _top_k_route(params: Params, cfg: MoeConfig, xt: jnp.ndarray):
 
 def moe_apply_grouped(params: Params, cfg: MoeConfig, x: jnp.ndarray, *,
                       expert_offset: int = 0, n_local_experts: int | None = None,
-                      capacity_factor: float = 1.0, psum_axis: str | None = None,
-                      parallelism=None):
+                      capacity_factor: float = 1.0, psum_axis: str | None = None):
     """Capacity-bounded grouped-matmul MoE (FLOPs ∝ top_k, not E).
 
     Scalable dispatch: no (T, E, C) one-hot.  Tokens hitting a local expert
@@ -185,9 +184,9 @@ def moe_apply_grouped(params: Params, cfg: MoeConfig, x: jnp.ndarray, *,
 
     # Sort-based dispatch: stable-sort assignments by (local) expert id —
     # position within the sorted run is the slot index.  O(N log N) with no
-    # (N, E) one-hot/cumsum intermediates (those dominate HBM+collective
-    # traffic at pod scale; see EXPERIMENTS.md §Perf).  Stable sort keeps
-    # earlier tokens first, so capacity drops match the cumsum formulation.
+    # (N, E) one-hot/cumsum intermediates, which grow with the expert
+    # count.  Stable sort keeps earlier tokens first, so capacity drops
+    # match the cumsum formulation.
     e_rel = eflat - expert_offset
     own = (e_rel >= 0) & (e_rel < E_l)
     e_key = jnp.where(own, e_rel, E_l).astype(jnp.int32)       # foreign -> end
@@ -210,14 +209,12 @@ def moe_apply_grouped(params: Params, cfg: MoeConfig, x: jnp.ndarray, *,
     xt_pad = jnp.concatenate([xt, jnp.zeros((1, D), xt.dtype)], axis=0)
     xe = xt_pad[slot_token[:-1]].reshape(E_l, C, D)            # (E_l, C, D)
 
-    con = parallelism.experts if parallelism is not None else (lambda t: t)
-    xe = con(xe)
     if cfg.activation in GATED:
-        h = _act(cfg.activation)(con(jnp.einsum("ecd,edf->ecf", xe, params["we_gate"]))) * \
-            con(jnp.einsum("ecd,edf->ecf", xe, params["we_up"]))
+        h = _act(cfg.activation)(jnp.einsum("ecd,edf->ecf", xe, params["we_gate"])) * \
+            jnp.einsum("ecd,edf->ecf", xe, params["we_up"])
     else:
-        h = gelu(con(jnp.einsum("ecd,edf->ecf", xe, params["we_up"])))
-    ye = con(jnp.einsum("ecf,efd->ecd", h, params["we_down"])).reshape(E_l * C, D)
+        h = gelu(jnp.einsum("ecd,edf->ecf", xe, params["we_up"]))
+    ye = jnp.einsum("ecf,efd->ecd", h, params["we_down"]).reshape(E_l * C, D)
 
     # combine back: each kept assignment adds w * ye[slot] to its token
     ye_pad = jnp.concatenate([ye, jnp.zeros((1, D), ye.dtype)], axis=0)

@@ -165,8 +165,7 @@ def _moe_dispatch(p, cfg: ArchConfig, x, parallelism):
     mcfg = cfg.moe_cfg()
     if parallelism is None or not parallelism.ep:
         return moe_apply_grouped(p, mcfg, x,
-                                 capacity_factor=cfg.moe_capacity_factor,
-                                 parallelism=parallelism)
+                                 capacity_factor=cfg.moe_capacity_factor)
     P = jax.sharding.PartitionSpec
     mesh = parallelism.mesh
     tp = mesh.shape[parallelism.tp_axis]
@@ -225,14 +224,14 @@ def _run_stack(blocks, cfg: ArchConfig, h, *, positions, frontend=None,
     def body(carry, stacks_slice):
         h, aux_sum = carry
         if parallelism is not None:
-            h = parallelism.constrain(h)   # seq-parallel saved carries
+            h = parallelism.resid(h)   # seq-parallel saved carries
         h, aux = fn(h, stacks_slice)
         return (h, aux_sum + aux), ()
 
     (h, aux_sum), _ = jax.lax.scan(body, (h, jnp.zeros((), jnp.float32)),
                                    tuple(blocks))
     if parallelism is not None:
-        h = parallelism.constrain(h)
+        h = parallelism.resid(h)
     return h, aux_sum
 
 
@@ -484,7 +483,7 @@ def prefill(params: Params, cfg: ArchConfig, tokens, *, max_len=None,
 
     def body(h, stacks_slice):
         if parallelism is not None:
-            h = parallelism.constrain(h)
+            h = parallelism.resid(h)
         return fn(h, stacks_slice)
 
     h, caches = jax.lax.scan(body, h, tuple(params["blocks"]))

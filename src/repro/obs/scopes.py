@@ -16,7 +16,7 @@ import re
 __all__ = ["SCOPES", "op_scopes", "scope_of"]
 
 #: The round's parts: the vmapped device half with its aux head, the
-#: server half (with its gradient accumulation and update), the ω ring's
+#: server half (its loss, gradient and update), the ω ring's
 #: read/merge/write, the staleness-weighted aggregation, and the SSD
 #: chunked scan of Mamba-2 layers (innermost, so its ops leave the half
 #: they run in).

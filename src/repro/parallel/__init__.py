@@ -1,1 +1,1 @@
-from .sharding import Parallelism, param_specs, batch_spec, opt_state_specs
+from .sharding import Parallelism, param_specs

@@ -39,12 +39,11 @@ class Parallelism:
     #   dp axes are already consumed, e.g. under vmap over FL device groups).
     # seq_shard: shard the residual-stream sequence dim over ``model``
     #   between blocks (SP) — saved scan carries shard too.
-    # interior: constrain per-head / ffn-hidden intermediates over ``model``
-    #   so weight gradients stay TP-sharded in backward.
+    # With ``constraints`` on, per-head / ffn-hidden intermediates are also
+    # constrained over ``model`` so weight gradients stay TP-sharded in
+    # backward.
     act_batch: tuple | None = None
     seq_shard: bool = False
-    interior: bool = True
-    moe_interior: bool = True         # pin expert-major tensors to EP axis
     constraints: bool = False         # master switch
 
     @property
@@ -69,35 +68,15 @@ class Parallelism:
 
     def ffn_hidden(self, h):
         """(B, S, F) MLP hidden — keeps dW_ffn TP-sharded in backward."""
-        if not (self.constraints and self.interior):
+        if not self.constraints:
             return h
         return self._constrain(h, P(self.act_batch, None, self.tp_axis))
 
     def heads(self, x):
         """(B, S, H, hd) per-head tensors — keeps dW_qkvo TP-sharded."""
-        if not (self.constraints and self.interior):
+        if not self.constraints:
             return x
         return self._constrain(x, P(self.act_batch, None, self.tp_axis, None))
-
-    def experts(self, x):
-        """(E, C, ·) expert-major MoE tensors — keeps expert dW sharded
-        over ``model`` (EP) instead of materialising full per-chip
-        partials in the backward pass."""
-        if not (self.constraints and self.interior and self.moe_interior):
-            return x
-        return self._constrain(
-            x, P(self.tp_axis, *([None] * (x.ndim - 1))))
-
-    # Back-compat alias used by the scan carry constraint
-    def constrain(self, h):
-        return self.resid(h)
-
-    @property
-    def dp_size(self) -> int:
-        out = 1
-        for a in self.dp_axes:
-            out *= self.mesh.shape[a]
-        return out
 
 
 # ---------------------------------------------------------------------------
@@ -197,23 +176,3 @@ def _validate(spec: P, shape, par: Parallelism) -> P:
         else:
             out.append(None)
     return P(*out)
-
-
-def batch_spec(par: Parallelism, rank: int = 2) -> P:
-    """Activations/batch: leading dim over all dp axes."""
-    axes = tuple(par.dp_axes)
-    return P(axes, *([None] * (rank - 1)))
-
-
-def opt_state_specs(opt_state, params_spec):
-    """Optimizer state shards like its parameters (ZeRO)."""
-    def spec_for(path_key, leaf):
-        return P()
-    # mu/nu mirror params; scalars replicated
-    out = {}
-    for k, v in opt_state.items():
-        if k in ("mu", "nu", "velocity"):
-            out[k] = params_spec
-        else:
-            out[k] = jax.tree.map(lambda _: P(), v)
-    return out

@@ -1,5 +1,6 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — smoke tests must see the
-real single CPU device; only launch/dryrun.py forces 512 host devices."""
+real single CPU device; a test that needs more starts a subprocess that
+sets its own device count."""
 import os
 
 import numpy as np

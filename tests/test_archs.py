@@ -1,6 +1,6 @@
 """Per-architecture smoke tests: reduced config of the same family, one
 forward + one hybrid train step on CPU; output shapes + no NaNs.
-(The FULL configs are exercised only via the dry-run — no allocation.)"""
+(The FULL configs are checked here by shape only — no allocation.)"""
 import jax
 import jax.numpy as jnp
 import pytest
@@ -113,14 +113,15 @@ def test_full_configs_match_assignment():
 
 
 def test_param_counts_plausible():
-    """Analytic 6·N·D accounting lands near the advertised sizes."""
-    from repro.analysis.roofline import count_params
+    """The parameter tree of each full config lands near its advertised
+    size (shapes only: ``eval_shape``, no allocation)."""
     expect = {"command-r-plus-104b": 104e9, "qwen3-32b": 32e9,
               "smollm-135m": 135e6, "gemma2-27b": 27e9,
               "mamba2-780m": 780e6, "qwen3-moe-235b-a22b": 235e9,
               "llama4-maverick-400b-a17b": 400e9,
               "jamba-1.5-large-398b": 398e9}
     for name, n in expect.items():
-        total, active = count_params(registry.get(name))
+        params = jax.eval_shape(lambda a=registry.get(name): tfm.init_params(
+            jax.random.PRNGKey(0), a, jnp.bfloat16))
+        total = sum(x.size for x in jax.tree.leaves(params))
         assert 0.5 * n < total < 1.6 * n, (name, total)
-        assert active <= total

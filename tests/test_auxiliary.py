@@ -74,26 +74,42 @@ def test_aux_loss_trains_device_block():
     assert losses[-1] < losses[0] - 0.05, losses
 
 
-def test_split_merge_roundtrip():
-    cfg = registry.smoke_config("qwen3-32b")
-    rng = jax.random.PRNGKey(0)
-    full = tfm.init_params(rng, cfg)
-    for l in (1, cfg.n_periods // 2, cfg.n_periods - 1):
-        dev, srv = tfm.split_params(full, cfg, l)
-        merged = tfm.merge_params(dev, srv, cfg)
-        jax.tree.map(lambda a, b: np.testing.assert_array_equal(a, b),
-                     full, merged)
+ARCHS = sorted(registry.ARCHS)
 
 
-def test_split_equivalence_full_forward():
-    """device_forward + server stack == full forward (same math, split)."""
-    cfg = registry.smoke_config("smollm-135m")
+@pytest.mark.parametrize("name,l_split", [
+    (name, l) for name in ARCHS
+    for l in range(1, registry.smoke_config(name).n_periods)])
+def test_split_merge_roundtrip(name, l_split):
+    cfg = registry.smoke_config(name)
+    full = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    dev, srv = tfm.split_params(full, cfg, l_split)
+    merged = tfm.merge_params(dev, srv, cfg)
+    jax.tree.map(lambda a, b: np.testing.assert_array_equal(a, b),
+                 full, merged)
+
+
+@pytest.mark.parametrize("name", ARCHS)
+def test_split_equivalence_full_forward(name):
+    """device_forward + the server half == full forward (same math, split
+    after the first period).  Enc-dec (whisper): the device half is the
+    encoder prefix on the frontend frames and the server finishes it and
+    runs the decoder; VLM: both halves see the frontend."""
+    cfg = registry.smoke_config(name)
     rng = jax.random.PRNGKey(0)
     full = tfm.init_params(rng, cfg)
     tok = jax.random.randint(rng, (2, 8), 0, cfg.vocab)
     lab = jax.random.randint(rng, (2, 8), 0, cfg.vocab)
-    want, _ = tfm.lm_loss(full, cfg, tok, lab, aux_weight=0.0)
-    dev, srv = tfm.split_params(full, cfg, 2)
-    acts, _ = tfm.device_forward(dev, cfg, tok)
-    got = tfm.server_forward_loss(srv, cfg, acts, lab, aux_weight=0.0)
+    fe = (jax.random.normal(rng, (2, cfg.frontend_len, cfg.d_model))
+          if cfg.frontend_len else None)
+    want, _ = tfm.lm_loss(full, cfg, tok, lab, frontend=fe, aux_weight=0.0)
+    dev, srv = tfm.split_params(full, cfg, 1)
+    if cfg.n_decoder_layers:
+        acts, _ = tfm.device_forward(dev, cfg, fe)
+        got = tfm.server_encdec_loss(srv, cfg, acts, tok, lab,
+                                     aux_weight=0.0)
+    else:
+        acts, _ = tfm.device_forward(dev, cfg, tok, frontend=fe)
+        got = tfm.server_forward_loss(srv, cfg, acts, lab, frontend=fe,
+                                      aux_weight=0.0)
     np.testing.assert_allclose(float(got), float(want), rtol=1e-5)

@@ -13,10 +13,10 @@ from repro.core import fedopt_step as F
 from repro.launch.mesh import make_debug_mesh
 
 
-def _setup(arch="smollm-135m", **kw):
+def _setup(arch="smollm-135m", H=2, per_group_batch=4):
     a = registry.smoke_config(arch)
     cfg = F.FedStepConfig(arch=a, l_split=1, n_groups=2, seq_len=16,
-                          per_group_batch=4, H=2, **kw)
+                          per_group_batch=per_group_batch, H=H)
     mesh = make_debug_mesh(1, 1)
     jitted, _, s_spec, _ = F.jit_train_step(cfg, mesh)
     state = jax.jit(lambda: F.init_train_state(jax.random.PRNGKey(0), cfg),
@@ -58,15 +58,23 @@ def test_groups_diverge_within_round():
 
 
 def test_pipelined_server_uses_previous_buffer():
-    """pipeline_acts: the first micro-iteration trains the server on the
-    (zero) initial buffer -> first-round server loss differs from the
-    unpipelined variant, later rounds converge similarly."""
-    _, step_p, state_p, batch = _setup(pipeline_acts=True)
-    _, step_n, state_n, _ = _setup(pipeline_acts=False)
-    _, mp = step_p(state_p, batch)
-    _, mn = step_n(state_n, batch)
-    assert not np.isclose(float(mp["s_loss"]), float(mn["s_loss"]),
-                          atol=1e-6)
+    """The server reads its ring slot before this iteration writes it
+    (H = 1, ω = 1): round 0 reads a never-written slot, so it has no
+    server loss and leaves the server half as it was, while the slot
+    fills with every group's rows; round 1 trains on round 0's rows."""
+    cfg, step, state, batch = _setup(H=1, per_group_batch=2)
+    srv0 = jax.tree.map(np.asarray, state["srv"])
+    state, m0 = step(state, batch)
+    assert float(m0["s_loss"]) == 0.0
+    for a, b in zip(jax.tree.leaves(srv0), jax.tree.leaves(state["srv"])):
+        np.testing.assert_array_equal(a, np.asarray(b))
+    assert int(state["act_buf"]["valid"].sum()) == \
+        cfg.n_groups * cfg.micro_batch
+    srv1 = jax.tree.map(np.asarray, state["srv"])
+    state, m1 = step(state, batch)
+    assert float(m1["s_loss"]) > 0.0
+    assert any(not np.array_equal(a, np.asarray(b)) for a, b in
+               zip(jax.tree.leaves(srv1), jax.tree.leaves(state["srv"])))
 
 
 def test_server_loss_decreases_over_rounds():
